@@ -1,6 +1,12 @@
-"""Distributed round step time: sharded flat exchange vs per-leaf shard_map.
+"""CPU-only count and parity check: sharded flat exchange vs per-leaf
+shard_map on 8 forced host devices.
 
-Measures one full DSGD train_step — local steps, residual add, per-shard
+This runs on XLA's CPU backend (forced host devices exist only there, so
+the child pins ``JAX_PLATFORMS=cpu`` even on an accelerator host).  Its
+byte counts and parity flags are exact; its times are CPU-backend wall
+times, never a device metric — chip timing belongs to the chip benchmark.
+
+Runs one full DSGD train_step — local steps, residual add, per-shard
 SBC compression, cross-client exchange, momentum masking — on a forced
 8-device host mesh ((2, 2, 2) 'pod'/'data'/'model'), two ways:
 
@@ -64,9 +70,10 @@ def _bench_child(repeats: int) -> dict:
     from repro.core import golomb
     from repro.core.channel import _iter_shard_blocks
     from repro.launch.dist import _lead_spec, build_dist_train, client_topology
+    from repro.launch.mesh import make_mesh
     from repro.models.model import build_model, make_param_specs
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = ModelConfig(
         name="bench", family="decoder", n_layers=4, d_model=128, n_heads=4,
         n_kv_heads=2, d_ff=256, vocab_size=256, dtype=jnp.float32,
@@ -218,7 +225,7 @@ def _bench_child(repeats: int) -> dict:
         return nbytes
 
     def wire_round_pk() -> int:
-        mean, _, own, (words, nbits) = ex_pk(res_pk, deltas)
+        mean, _, own, (words, nbits, _) = ex_pk(res_pk, deltas)
         jax.block_until_ready(jax.tree.leaves(mean)[0])
         w_all = np.asarray(jax.device_get(words))
         nb_all = np.asarray(jax.device_get(nbits))

@@ -63,18 +63,10 @@ from repro.obs import NULL_TELEMETRY
 from repro.core.policy import CompressionPolicy, CompressorState, ResolvedPolicy
 from repro.core.wire import Wire, wire_for
 
-try:  # jax >= 0.7 moved shard_map to the top level
-    from jax import shard_map as _shard_map
+def shard_map(f, *, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=False)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_rep=False)
 
 PyTree = Any
 
@@ -472,14 +464,15 @@ class ShardedGspmdChannel:
         )
         packed = None
         if self.device_pack:
-            # extra outputs: this round's device-packed Golomb word
-            # buffers + exact per-row bit counts for EVERY (client,
-            # shard) — same layout/sharding as the flat residual
+            # extra outputs: this round's upload of EVERY (client, shard)
+            # — device-packed Golomb word buffers, exact per-row bit
+            # counts and per-row μ — same layout/sharding as the flat
+            # residual
             mean_leaves, new_residual, own_leaves, packed = shard_map(
                 lambda res, *leaves: self.exchange_flat(res, leaves, need_own),
                 mesh=mesh, in_specs=(res_spec,) + tuple(in_specs),
                 out_specs=(tuple(in_specs), res_spec, own_specs,
-                           (res_spec, res_spec)),
+                           (res_spec, res_spec, res_spec)),
             )(residual, *delta_leaves)
         elif self.flat_space is not None:
             mean_leaves, new_residual, own_leaves = shard_map(
@@ -550,10 +543,10 @@ class ShardedGspmdChannel:
         bodies = [leaf[0] for leaf in leaves]
         packed = None
         if self.device_pack:
-            mean_f, own_f, new_res_f, words, nbits = space.exchange_local(
+            mean_f, own_f, new_res_f, words, nbits, mu = space.exchange_local(
                 bodies, res[0, 0], device_pack=True
             )
-            packed = (words[None, None], nbits[None, None])
+            packed = (words[None, None], nbits[None, None], mu[None, None])
         else:
             fn = (space.exchange_local if self.flat_engine == "exact"
                   else space.exchange_local_hist)

@@ -18,12 +18,14 @@ Two engines share the layout:
                policies dispatch to.
 
 ``compress_hist``  the *device* engine — the segment-aware Pallas kernels
-               (:mod:`repro.kernels.flat`): two-pass histogram threshold
-               selection, masked moments, fused binarize+residual, each
-               launched ONCE over the flat buffer.  Approximate survivor
-               counts (like :func:`repro.kernels.ops.sbc_compress_hist`,
-               whose per-leaf semantics it reproduces); runs interpret-mode
-               on CPU, ``interpret=False`` on TPU.
+               (:func:`repro.kernels.ops.seg_sbc_hist`): two-pass histogram
+               threshold, tier counts, masked moments, fused
+               binarize+residual, each launched ONCE over the flat buffer.
+               Exactly k survivors per segment; the threshold is a
+               histogram bucket, so WHICH entries survive may differ from
+               top-k within it (ties kept spread by position); compiled on
+               TPU, interpreted elsewhere
+               (:func:`repro.kernels.resolve_interpret`).
 
 Layout contract (stable; documented in DESIGN.md §10):
 
@@ -51,9 +53,7 @@ import numpy as np
 
 from repro.core.golomb import golomb_bstar
 from repro.core.stages import LeafCompressed, k_for
-from repro.kernels.flat import seg_binarize_apply, seg_hist2side, seg_moments
-from repro.kernels.hist2side import SPAN_OCTAVES, bucket_lower_edges
-from repro.kernels.ops import _side_threshold, on_tpu
+from repro.kernels.ops import seg_sbc_hist
 from repro.kernels.pack import (
     bits_from_positions,
     golomb_decode_rows,
@@ -64,115 +64,23 @@ from repro.kernels.pack import (
 PyTree = Any
 
 
-def _pad_maps(
-    offsets: Sequence[int], sizes: Sequence[int], n_pad: int
-) -> Tuple[np.ndarray, np.ndarray]:
-    """Padded-position → raw-concat position map + validity mask: turns
-    flatten into ONE gather + ONE select instead of a pad+concat per
-    segment (pad slots gather position 0 and are masked to zero)."""
-    pad_to_raw = np.zeros((n_pad,), np.int32)
-    pad_valid = np.zeros((n_pad,), bool)
-    raw = 0
-    for off, size in zip(offsets, sizes):
-        pad_to_raw[off:off + size] = np.arange(raw, raw + size, dtype=np.int32)
-        pad_valid[off:off + size] = True
-        raw += size
-    return pad_to_raw, pad_valid
+def _flatten_padded(leaves, offsets: Sequence[int], n_pad: int) -> jax.Array:
+    """Flatten ``leaves`` into the block-padded layout: leaf i at
+    ``offsets[i]``, zeros between leaves and up to ``n_pad`` (one
+    concatenate; no position-map constant, which at 10⁸ parameters would
+    be embedded in the compiled program) — shared by
+    :class:`FlatParamSpace` and the sharded space."""
+    pieces, pos = [], 0
+    for leaf, off in zip(leaves, offsets):
+        if off > pos:
+            pieces.append(jnp.zeros((off - pos,), jnp.float32))
+        flat = jnp.asarray(leaf).reshape(-1).astype(jnp.float32)
+        pieces.append(flat)
+        pos = off + flat.shape[0]
+    if n_pad > pos:
+        pieces.append(jnp.zeros((n_pad - pos,), jnp.float32))
+    return jnp.concatenate(pieces) if len(pieces) > 1 else pieces[0]
 
-
-def _flatten_padded(leaves, pad_to_raw, pad_valid, contiguous: bool) -> jax.Array:
-    """Flatten ``leaves`` into the block-padded layout described by the
-    maps of :func:`_pad_maps` (identical math to the original per-space
-    flatten — shared by :class:`FlatParamSpace` and the sharded space)."""
-    raw = [jnp.asarray(leaf).reshape(-1).astype(jnp.float32) for leaf in leaves]
-    raw_flat = jnp.concatenate(raw) if len(raw) > 1 else raw[0]
-    if contiguous:
-        return raw_flat
-    gathered = jnp.take(raw_flat, jnp.asarray(pad_to_raw), mode="clip")
-    return jnp.where(jnp.asarray(pad_valid), gathered, 0.0)
-
-
-def _hist_pipeline(
-    acc_flat: jax.Array,
-    bounds: Sequence[Tuple[int, int]],
-    ks: Sequence[int],
-    rates: Sequence[float],
-    seg_of_block: np.ndarray,
-    n_blocks: int,
-    bm: int,
-    lanes: int,
-    nbins: int,
-    interpret: bool,
-) -> Tuple[jax.Array, jax.Array, dict]:
-    """The three segment-aware Pallas passes over one flat buffer.
-
-    ``bounds`` is the static per-segment ``(offset, size)`` table.  Shared
-    by :meth:`FlatParamSpace.compress_hist` (per-leaf segments) and
-    :meth:`ShardedFlatParamSpace.exchange_local_hist` (per-shard
-    segments inside ``shard_map``); per-segment semantics match
-    :func:`repro.kernels.ops.sbc_compress_hist` bit for bit at matching
-    tiles.  Returns ``(delta_star_flat, residual_flat, stats)``.
-    """
-    from repro.core.golomb import expected_position_bits
-
-    nseg = len(bounds)
-    xpad = acc_flat.reshape(n_blocks * bm, lanes)
-    sob = jnp.asarray(seg_of_block, jnp.float32)[:, None]
-
-    # per-segment |x| range for the coarse pass (same rule as
-    # ops.sbc_compress_hist; max is order-independent → exact)
-    absmax = jnp.stack([
-        jnp.max(jnp.abs(acc_flat[off:off + size])) for off, size in bounds
-    ]) + 1e-30
-    lo0 = absmax * 2.0 ** -SPAN_OCTAVES
-    hi0 = absmax * 1.0001
-
-    def block_params(*cols, seg: bool = True):
-        rows = [c[seg_of_block][:, None] for c in cols]
-        if seg:
-            rows = [sob] + rows
-        return jnp.concatenate(rows, axis=1)
-
-    kf = jnp.asarray(ks, jnp.float32)
-    vthresh = jax.vmap(_side_threshold)
-    vedges = jax.vmap(lambda lo, hi: bucket_lower_edges(lo, hi, nbins))
-
-    h1 = seg_hist2side(
-        xpad, block_params(lo0, hi0, lo0, hi0), nseg=nseg, nbins=nbins,
-        bm=bm, lanes=lanes, interpret=interpret,
-    )
-    edges0 = vedges(lo0, hi0)
-    lo_p, hi_p, above_p = vthresh(h1[:, 0], edges0, kf)
-    lo_n, hi_n, above_n = vthresh(h1[:, 1], edges0, kf)
-
-    h2 = seg_hist2side(
-        xpad, block_params(lo_p, hi_p, lo_n, hi_n), nseg=nseg, nbins=nbins,
-        bm=bm, lanes=lanes, interpret=interpret,
-    )
-    t_pos, _, _ = vthresh(h2[:, 0], vedges(lo_p, hi_p), kf - above_p)
-    t_neg, _, _ = vthresh(h2[:, 1], vedges(lo_n, hi_n), kf - above_n)
-
-    mom = seg_moments(
-        xpad, block_params(t_pos, t_neg), nseg=nseg,
-        bm=bm, lanes=lanes, interpret=interpret,
-    )
-    mu_pos = mom[:, 0, 0] / jnp.maximum(mom[:, 0, 1], 1.0)
-    mu_neg = -mom[:, 1, 0] / jnp.maximum(mom[:, 1, 1], 1.0)
-    pos_wins = mu_pos > mu_neg
-    mu = jnp.where(pos_wins, mu_pos, -mu_neg)
-    count = jnp.where(pos_wins, mom[:, 0, 1], mom[:, 1, 1])
-
-    out_pad, res_pad = seg_binarize_apply(
-        xpad,
-        block_params(t_pos, t_neg, mu, pos_wins.astype(jnp.float32),
-                     seg=False),
-        bm=bm, lanes=lanes, interpret=interpret,
-    )
-    ebits = jnp.asarray(
-        [expected_position_bits(min(p, 1.0)) for p in rates], jnp.float32
-    )
-    stats = {"mu": mu, "count": count, "nbits": count * ebits + 32.0}
-    return out_pad.reshape(-1), res_pad.reshape(-1), stats
 
 def supports(resolved) -> bool:
     """True when every leaf of the resolved policy has a flat-fast codec
@@ -216,26 +124,15 @@ class FlatParamSpace:
         )
         self.n_pad = self.n_blocks * per_block
         self.n_total = sum(s.size for s in self.segments)
-        # static per-block segment ids (one leaf per block, by construction)
-        seg_of_block = np.zeros((self.n_blocks,), np.int32)
         res_mask = np.zeros((self.n_pad,), bool)
         dense_mask = np.zeros((self.n_pad,), bool)
-        for i, s in enumerate(self.segments):
-            blk0 = s.offset // per_block
-            nblk = max(1, -(-s.size // per_block))
-            seg_of_block[blk0:blk0 + nblk] = i
+        for s in self.segments:
             if s.use_residual:
                 res_mask[s.offset:s.offset + s.size] = True
             if s.kind == "dense":
                 dense_mask[s.offset:s.offset + s.size] = True
-        self.seg_of_block = seg_of_block
         self._res_mask = res_mask
         self._dense_mask = dense_mask
-        self._pad_to_raw, self._pad_valid = _pad_maps(
-            [s.offset for s in self.segments],
-            [s.size for s in self.segments],
-            self.n_pad,
-        )
         # pad slots self-maintain zeros under acc/dense/residual updates, so
         # the mask-free fast branch only needs every LEAF to use residuals
         self._all_residual = all(s.use_residual for s in self.segments)
@@ -276,8 +173,7 @@ class FlatParamSpace:
 
     def _flatten_leaves(self, leaves) -> jax.Array:
         return _flatten_padded(
-            leaves, self._pad_to_raw, self._pad_valid,
-            contiguous=self.n_pad == self.n_total,
+            leaves, [s.offset for s in self.segments], self.n_pad
         )
 
     def unflatten(self, flat: jax.Array, cast: bool = True) -> PyTree:
@@ -437,13 +333,12 @@ class FlatParamSpace:
         rates,
         *,
         nbins: int = 128,
-        interpret: Optional[bool] = None,
     ) -> tuple:
         """Histogram-threshold SBC over the flat buffer — the Pallas engine.
 
         Per-segment semantics match :func:`repro.kernels.ops.sbc_compress_hist`
-        (approximate survivor counts; exact residual identity acc = ΔW* + R),
-        but the three passes launch ONCE each over the whole parameter set.
+        (exactly k survivors per segment; residual identity acc = ΔW* + R),
+        but every pass launches ONCE over the whole parameter set.
         Requires an all-"sbc" policy.  Returns ``(dense_tree, new_state,
         stats)`` with per-segment ``stats = {mu, count, nbits}``.
         """
@@ -453,13 +348,11 @@ class FlatParamSpace:
                 "belong to the exact engine"
             )
         rates = self._check_rates(rates)
-        if interpret is None:
-            interpret = not on_tpu()
-        key = ("hist", rates, nbins, bool(interpret))
+        key = ("hist", rates, nbins)
         fn = self._jitted.get(key)
         if fn is None:
             fn = jax.jit(lambda leaves, res: self._compress_hist(
-                leaves, res, rates, nbins, interpret))
+                leaves, res, rates, nbins))
             self._jitted[key] = fn
         leaves = self.resolved._leaves_of(delta)
         residual = state.residual if self.resolved.any_residual else None
@@ -471,20 +364,17 @@ class FlatParamSpace:
         )
         return self.unflatten(dense_flat), new_state, stats
 
-    def _compress_hist(self, leaves, residual, rates, nbins, interpret):
+    def _compress_hist(self, leaves, residual, rates, nbins):
         delta_flat = self._flatten_leaves(leaves)
         acc_flat = delta_flat if residual is None else delta_flat + residual
-        dense_flat, res_flat, stats = _hist_pipeline(
+        dense_flat, res_flat, stats = seg_sbc_hist(
             acc_flat,
             bounds=[(s.offset, s.size) for s in self.segments],
             ks=self._ks(rates),
             rates=rates,
-            seg_of_block=self.seg_of_block,
-            n_blocks=self.n_blocks,
             bm=self.bm,
             lanes=self.lanes,
             nbins=nbins,
-            interpret=interpret,
         )
         new_res = res_flat if residual is not None else None
         return dense_flat, new_res, stats
@@ -551,18 +441,10 @@ class ShardedFlatParamSpace:
         self.n_blocks = sum(max(1, -(-sz // per_block)) for sz in sizes)
         self.n_pad = self.n_blocks * per_block
         self.n_total = sum(sizes)
-        seg_of_block = np.zeros((self.n_blocks,), np.int32)
         dense_mask = np.zeros((self.n_pad,), bool)
-        for i, (s, sz) in enumerate(zip(self.segments, sizes)):
-            blk0 = s.offset // per_block
-            nblk = max(1, -(-sz // per_block))
-            seg_of_block[blk0:blk0 + nblk] = i
+        for s, sz in zip(self.segments, sizes):
             if s.kind == "dense":
                 dense_mask[s.offset:s.offset + sz] = True
-        self.seg_of_block = seg_of_block
-        self._pad_to_raw, self._pad_valid = _pad_maps(
-            [s.offset for s in self.segments], sizes, self.n_pad
-        )
         self._dense_idx = np.flatnonzero(dense_mask).astype(np.int32)
         # static maps for the packed sparse exchange: every (row, k-slot)
         # of every sparse segment gets one position slot; ``_pos_row``
@@ -642,8 +524,7 @@ class ShardedFlatParamSpace:
     def flatten_local(self, bodies) -> jax.Array:
         """Local leaf shards (in segment order) → one local flat buffer."""
         return _flatten_padded(
-            bodies, self._pad_to_raw, self._pad_valid,
-            contiguous=self.n_pad == self.n_total,
+            bodies, [s.offset for s in self.segments], self.n_pad
         )
 
     def unflatten_local(self, flat: jax.Array) -> List[jax.Array]:
@@ -687,7 +568,6 @@ class ShardedFlatParamSpace:
         res_flat: Optional[jax.Array],
         *,
         device_pack: bool = False,
-        interpret: Optional[bool] = None,
     ) -> tuple:
         """Inside shard_map: compress this device's shard of every leaf
         and exchange.  Returns ``(mean_flat, own_flat, new_res_flat)`` —
@@ -709,11 +589,11 @@ class ShardedFlatParamSpace:
         :func:`~repro.kernels.pack.seg_packbits` launch over the whole
         local stream), the all_gather moves those word buffers
         (≈ b̄(p) bits/position instead of 32), and receivers recover
-        positions with the pointer-doubling device decoder.  Returns two
-        extra outputs ``(words u32[n_pack_words], nbits i32[n_mu])`` —
-        this shard's packed streams + exact per-row bit counts, which
-        are byte-identical to the host ``encode_positions_packed`` and
-        feed the per-client wire metering.  The aggregated update,
+        positions with the pointer-doubling device decoder.  Returns three
+        extra outputs ``(words u32[n_pack_words], nbits i32[n_mu],
+        mu f32[n_mu])`` — this shard's upload: packed streams
+        (byte-identical to the host ``encode_positions_packed``), exact
+        per-row bit counts (the per-client wire metering) and per-row μ.  The aggregated update,
         residual, and ΔW* are bit-identical to ``device_pack=False``.
         """
         acc = self.flatten_local(bodies)
@@ -755,9 +635,7 @@ class ShardedFlatParamSpace:
 
         words = nbits = None
         if device_pack:
-            if interpret is None:
-                interpret = not on_tpu()
-            words, nbits = self._pack_local(idx_parts, interpret)
+            words, nbits = self._pack_local(idx_parts)
 
         if self.client_axes and self.n_clients > 1 and pos_parts:
             # THE exchange: the packed (positions, μ) streams cross the
@@ -796,12 +674,12 @@ class ShardedFlatParamSpace:
 
         new_res = acc - own if res_flat is not None else None
         if device_pack:
-            return mean, own, new_res, words, nbits
+            return mean, own, new_res, words, nbits, mu
         return mean, own, new_res
 
     # ------------------------------------------------- device wire packing
 
-    def _pack_local(self, idx_parts: List[jax.Array], interpret: bool) -> tuple:
+    def _pack_local(self, idx_parts: List[jax.Array]) -> tuple:
         """This shard's survivors → (packed u32 words, per-row bit counts).
 
         Builds every (segment, row)'s Golomb bit stream at its static
@@ -820,15 +698,9 @@ class ShardedFlatParamSpace:
             )(jnp.sort(idx_s, axis=1))
             chunks.append(bits_s.reshape(-1))
             nb_parts.append(nb_s)
-        allbits = jnp.concatenate(chunks)
-        pad = -allbits.shape[0] % (32 * self.lanes)
-        if pad:
-            allbits = jnp.concatenate(
-                [allbits, jnp.zeros((pad,), allbits.dtype)]
-            )
-        planes = allbits.reshape(-1, 32).T
-        words = seg_packbits(planes, lanes=self.lanes, interpret=interpret)
-        return words[: self.n_pack_words], jnp.concatenate(nb_parts)
+        planes = jnp.concatenate(chunks).reshape(-1, 32).T
+        words = seg_packbits(planes, lanes=self.lanes)
+        return words, jnp.concatenate(nb_parts)
 
     def _decode_gathered(self, gw: jax.Array) -> jax.Array:
         """Gathered word buffers u32[C, n_pack_words] → global positions
@@ -856,14 +728,13 @@ class ShardedFlatParamSpace:
         res_flat: Optional[jax.Array],
         *,
         nbins: int = 128,
-        interpret: Optional[bool] = None,
     ) -> tuple:
         """Inside shard_map: the segment-aware Pallas passes
         (:mod:`repro.kernels.flat`) over this device's local flat buffer
         — one launch per pass per device, per-(segment, shard) μ±.
 
-        Approximate survivor counts (histogram thresholds, like
-        ``ops.sbc_compress_hist``); the exchange is a ``pmean`` of the
+        Exactly k survivors per (leaf, shard) segment (histogram
+        thresholds, like ``ops.sbc_compress_hist``); the exchange is a ``pmean`` of the
         binarized ΔW* over the client axes (no packed positions stream —
         that needs the exact engine).  Requires an all-sparse policy.
         """
@@ -872,22 +743,17 @@ class ShardedFlatParamSpace:
                 "exchange_local_hist needs an all-SBC policy; dense/skip "
                 "leaves belong to the exact engine"
             )
-        if interpret is None:
-            interpret = not on_tpu()
         acc = self.flatten_local(bodies)
         if res_flat is not None:
             acc = res_flat + acc
-        own, res, _stats = _hist_pipeline(
+        own, res, _stats = seg_sbc_hist(
             acc,
             bounds=[(s.offset, s.rows * s.n_loc) for s in self.segments],
             ks=[k_for(s.rows * s.n_loc, s.rate) for s in self.segments],
             rates=[s.rate for s in self.segments],
-            seg_of_block=self.seg_of_block,
-            n_blocks=self.n_blocks,
             bm=self.bm,
             lanes=self.lanes,
             nbins=nbins,
-            interpret=interpret,
         )
         mean = own
         for ax in self.client_axes:

@@ -420,14 +420,13 @@ class Wire:
         return self.pack_with_bits(compressed)[0]
 
     def pack_with_bits(
-        self, compressed: PyTree, *, device_pack: bool = False,
-        interpret=None,
+        self, compressed: PyTree, *, device_pack: bool = False
     ) -> Tuple[bytes, int]:
         """Pack and return (buffer, exact payload bits) in one pass — the
         bits are what ``measured_bits`` reports, without re-serializing.
 
         ``device_pack=True`` produces every golomb position stream with
-        the fused select→pack Pallas kernel (:mod:`repro.kernels.pack`)
+        the device select→pack path (:mod:`repro.kernels.pack`)
         instead of the host numpy encoder; the serialized buffer is
         byte-identical, but the bytes come off the device as a single
         big-endian word-buffer copy (``golomb.packed_words_to_bytes``).
@@ -438,19 +437,17 @@ class Wire:
         for comp, spec in zip(leaves, self.specs):
             payload_pos = None
             if device_pack and spec.encoder == "golomb" and spec.selector != "skip":
-                payload_pos = _device_golomb_payload(comp, spec, interpret)
+                payload_pos = _device_golomb_payload(comp, spec)
             payload, bits = pack_leaf(_to_numpy(comp), spec, payload_pos)
             total_bits += bits
             out.append(struct.pack("<I", len(payload)))
             out.append(payload)
         return b"".join(out), total_bits
 
-    def pack_device(self, compressed: PyTree, *, interpret=None) -> bytes:
+    def pack_device(self, compressed: PyTree) -> bytes:
         """Device-side ``pack``: byte-identical output, golomb position
-        streams packed on-device (one fused select→pack launch per leaf)."""
-        return self.pack_with_bits(
-            compressed, device_pack=True, interpret=interpret
-        )[0]
+        streams packed on-device (one select→pack call per leaf)."""
+        return self.pack_with_bits(compressed, device_pack=True)[0]
 
     def unpack(self, data: bytes) -> PyTree:
         """Byte buffer → dense update pytree (numpy float32 leaves)."""
@@ -523,29 +520,26 @@ def _to_numpy(comp: LeafCompressed) -> LeafCompressed:
 
 
 def _device_golomb_payload(
-    comp: LeafCompressed, spec: LeafSpec, interpret=None
+    comp: LeafCompressed, spec: LeafSpec
 ) -> Tuple[bytes, int]:
     """One leaf's golomb position payload off the device packer.
 
     Builds the selection mask from the surviving indices and runs the
-    fused select→pack kernel; the returned bytes are the big-endian view
+    select→pack path; the returned bytes are the big-endian view
     of the ``uint32`` word buffer, truncated to ``ceil(bits/8)`` — the
     device-to-bytes copy that replaces the host ``np.packbits`` path.
     """
     import jax.numpy as jnp
 
-    from repro.kernels.ops import on_tpu
     from repro.kernels.pack import seg_select_pack
 
     idx = np.asarray(comp.idx)
     k = int(idx.size)
     if k == 0:
         return b"", 0
-    if interpret is None:
-        interpret = not on_tpu()
     mask = jnp.zeros((spec.n,), jnp.int32).at[jnp.asarray(idx, jnp.int32)].set(1)
     words, nbits = seg_select_pack(
-        mask[None], k=k, bstar=golomb.golomb_bstar(spec.p), interpret=interpret
+        mask[None], k=k, bstar=golomb.golomb_bstar(spec.p)
     )
     nb = int(nbits[0])
     return golomb.packed_words_to_bytes(np.asarray(jax.device_get(words[0])), nb), nb

@@ -28,6 +28,12 @@ import jax.numpy as jnp
 
 PyTree = dict
 
+# Largest vocabulary whose Markov chain is kept as a dense V×V table (64 MiB
+# of f32).  Above it the table would not fit beside the model (32k vocab:
+# 4 GB, captured as a constant in every jitted sampler), so each row's
+# logits are drawn from the row's own key when that token is sampled.
+DENSE_CHAIN_MAX_VOCAB = 4096
+
 
 @dataclasses.dataclass(frozen=True)
 class Task:
@@ -66,18 +72,28 @@ def make_lm_task(
     floor = 0.0
 
     if kind == "markov":
-        logits = jax.random.normal(jax.random.fold_in(base, 17), (vocab, vocab))
-        logits = logits / max(temperature, 1e-3)
-        probs = jax.nn.softmax(logits, axis=-1)
+        chain_key = jax.random.fold_in(base, 17)
+        t = max(temperature, 1e-3)
+        if vocab <= DENSE_CHAIN_MAX_VOCAB:
+            logits = jax.random.normal(chain_key, (vocab, vocab)) / t
+            probs = jax.nn.softmax(logits, axis=-1)
+            log_probs = jnp.log(probs)
+            next_logits = log_probs.__getitem__
+        else:
+            def row_logits(tok):
+                key = jax.random.fold_in(chain_key, tok)
+                return jax.random.normal(key, (vocab,)) / t
+
+            next_logits = jax.vmap(row_logits)
+            probs = jax.nn.softmax(next_logits(jnp.arange(64)), axis=-1)
         # entropy floor ≈ mean row entropy (stationary dist of a dense random
-        # chain is near-uniform)
+        # chain is near-uniform; 64 sampled rows above the dense-table size)
         row_ent = -jnp.sum(probs * jnp.log(probs + 1e-12), axis=-1)
         floor = float(jnp.mean(row_ent))
-        log_probs = jnp.log(probs)
 
         def gen_tokens(rng: jax.Array) -> jax.Array:
             def step(tok, r):
-                nxt = jax.random.categorical(r, log_probs[tok])
+                nxt = jax.random.categorical(r, next_logits(tok))
                 return nxt, nxt
 
             r0, rs = jax.random.split(rng)

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: fused sparse-binarize apply + residual update.
+"""Fused sparse-binarize apply + residual update (one tensor).
 
 The final pass of SBC compression (paper Alg. 2 lines 5-8 + Eq. 2):
 
@@ -8,32 +8,20 @@ The final pass of SBC compression (paper Alg. 2 lines 5-8 + Eq. 2):
 
 Unfused this is ~4 HBM round-trips (mask, select, subtract, write); fused it
 is one read and two writes, which matters because compression streams the
-ENTIRE parameter set once per communication round.  Elementwise over
-(BM, LANES) VMEM tiles; padding zeros produce ΔW* = 0 and R = 0 in the pad
-region, which the caller slices off.
+ENTIRE parameter set once per communication round.  A one-segment launch
+of :func:`repro.kernels.flat.seg_binarize_apply` with no tie set; padding zeros produce
+ΔW* = 0 and R = 0 in the pad region, which is sliced off.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
+from repro.kernels.flat import seg_binarize_apply
 from repro.kernels.hist2side import DEFAULT_BM, DEFAULT_LANES, _pad_2d
-
-
-def _apply_kernel(x_ref, tpos_ref, tneg_ref, mu_ref, side_ref, out_ref, res_ref):
-    x = x_ref[...]
-    tpos = tpos_ref[0, 0]
-    tneg = tneg_ref[0, 0]
-    mu = mu_ref[0, 0]
-    pos_wins = side_ref[0, 0] > 0.5
-
-    mask = jnp.where(pos_wins, x >= tpos, x <= -tneg)
-    out = jnp.where(mask, mu, 0.0)
-    out_ref[...] = out
-    res_ref[...] = x - out
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "lanes", "interpret"))
@@ -46,31 +34,17 @@ def binarize_apply(
     *,
     bm: int = DEFAULT_BM,
     lanes: int = DEFAULT_LANES,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (ΔW*, R_new), both f32 of the original flat length."""
     n = flat.shape[0]
-    x, nblocks = _pad_2d(flat, bm, lanes)
-    scal = lambda v: jnp.asarray(v, jnp.float32).reshape(1, 1)
-
-    out, res = pl.pallas_call(
-        _apply_kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((bm, lanes), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, lanes), lambda i: (i, 0)),
-            pl.BlockSpec((bm, lanes), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(x.shape, jnp.float32),
-            jax.ShapeDtypeStruct(x.shape, jnp.float32),
-        ],
-        interpret=interpret,
-    )(x, scal(t_pos), scal(t_neg), scal(mu), scal(pos_wins))
+    tp, tn, mu, side = (jnp.asarray(v, jnp.float32)
+                        for v in (t_pos, t_neg, mu, pos_wins))
+    xpad = _pad_2d(flat, bm, lanes)
+    one = jnp.ones((), jnp.float32)
+    out, res = seg_binarize_apply(
+        xpad, jnp.stack([tp, tp, one, one, tn, tn, one, one, mu, side])[None],
+        jnp.zeros((xpad.shape[0] // bm, 2), jnp.float32), blk_starts=(0,),
+        bm=bm, lanes=lanes, interpret=interpret,
+    )
     return out.reshape(-1)[:n], res.reshape(-1)[:n]

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: two-sided log-magnitude range histogram of ΔW.
+"""Two-sided log-magnitude range histogram of ΔW (one tensor).
 
 This is the streaming pass of the TPU-native replacement for the paper's
 O(n log n) top-p% sort (DESIGN.md §2).  One HBM→VMEM pass bins the positive
@@ -9,61 +9,34 @@ survival counts from the previous, coarser pass).
 
 Survival counts over the histogram give the top-k thresholds t⁺/t⁻ to one
 bucket's resolution; a second zoomed-in pass over the winning bucket refines
-them to nbins² effective resolution (see ops.threshold_two_pass).
+them to nbins² effective resolution (see ops.seg_sbc_hist).
 
-Layout: the flat tensor is padded with zeros and reshaped to (R, LANES);
-zeros are out-of-range for any lo > 0 so padding needs no mask.  The grid
-walks row-blocks sequentially and accumulates into a single (2, nbins)
-output block — the canonical Pallas grid-reduction pattern.  VMEM working
-set per step ≈ BM·LANES·4 B ≈ 1 MiB at the default (256, 1024).
+The flat tensor is zero-padded to whole (bm, lanes) blocks and run as a
+one-segment launch of :func:`repro.kernels.flat.seg_hist2side`; zeros are
+out of range for any lo > 0, so padding needs no mask.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.flat import seg_hist2side
+
 SPAN_OCTAVES = 30.0  # dynamic range of the coarse pass: [absmax·2⁻³⁰, absmax)
 
-DEFAULT_BM = 256
-DEFAULT_LANES = 1024
+DEFAULT_BM = 8
+DEFAULT_LANES = 128
 
 
-def _hist_kernel(x_ref, lo_ref, hi_ref, hist_ref, *, nbins: int):
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
-
-    x = x_ref[...]  # (bm, lanes) f32
-    absx = jnp.abs(x)
-    bins = jax.lax.broadcasted_iota(jnp.int32, (nbins, 1, 1), 0)
-
-    rows = []
-    # side 0 bins positive entries, side 1 bins |negative| entries; each side
-    # has its own [lo, hi) range so the refinement pass can zoom per side.
-    for side, sel in ((0, x > 0.0), (1, x < 0.0)):
-        lo = lo_ref[0, side]
-        hi = hi_ref[0, side]
-        in_range = sel & (absx >= lo) & (absx < hi)
-        log_lo = jnp.log2(jnp.maximum(lo, 1e-38))
-        log_hi = jnp.log2(jnp.maximum(hi, 2e-38))
-        f = (jnp.log2(jnp.maximum(absx, 1e-38)) - log_lo) / (log_hi - log_lo)
-        bucket = jnp.clip((f * nbins).astype(jnp.int32), 0, nbins - 1)
-        match = bucket[None, :, :] == bins  # (nbins, bm, lanes)
-        rows.append(jnp.sum(jnp.where(match & in_range[None], 1.0, 0.0), axis=(1, 2)))
-
-    hist_ref[...] += jnp.stack(rows, axis=0)
-
-
-def _pad_2d(flat: jax.Array, bm: int, lanes: int) -> tuple[jax.Array, int]:
+def _pad_2d(flat: jax.Array, bm: int, lanes: int) -> jax.Array:
     n = flat.shape[0]
-    per_block = bm * lanes
-    nblocks = max(1, pl.cdiv(n, per_block))
-    padded = nblocks * per_block
+    padded = max(1, pl.cdiv(n, bm * lanes)) * bm * lanes
     x = jnp.zeros((padded,), jnp.float32).at[:n].set(flat.astype(jnp.float32))
-    return x.reshape(nblocks * bm, lanes), nblocks
+    return x.reshape(-1, lanes)
 
 
 @functools.partial(jax.jit, static_argnames=("nbins", "bm", "lanes", "interpret"))
@@ -75,28 +48,19 @@ def hist2side(
     nbins: int = 128,
     bm: int = DEFAULT_BM,
     lanes: int = DEFAULT_LANES,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """(2, nbins) histogram: row 0 = positive entries, row 1 = |negatives|.
 
     ``lo``/``hi`` broadcast to shape (2,): per-side magnitude ranges.
     """
-    x, nblocks = _pad_2d(flat, bm, lanes)
-    lo2 = jnp.broadcast_to(jnp.asarray(lo, jnp.float32), (2,)).reshape(1, 2)
-    hi2 = jnp.broadcast_to(jnp.asarray(hi, jnp.float32), (2,)).reshape(1, 2)
-
-    return pl.pallas_call(
-        functools.partial(_hist_kernel, nbins=nbins),
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((bm, lanes), lambda i: (i, 0)),
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((2, nbins), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((2, nbins), jnp.float32),
-        interpret=interpret,
-    )(x, lo2, hi2)
+    lo2 = jnp.broadcast_to(jnp.asarray(lo, jnp.float32), (2,))
+    hi2 = jnp.broadcast_to(jnp.asarray(hi, jnp.float32), (2,))
+    params = jnp.stack([lo2[0], hi2[0], lo2[1], hi2[1]])[None]
+    return seg_hist2side(
+        _pad_2d(flat, bm, lanes), params, blk_starts=(0,), nbins=nbins,
+        bm=bm, lanes=lanes, interpret=interpret,
+    )[0]
 
 
 def bucket_lower_edges(lo: jax.Array, hi: jax.Array, nbins: int) -> jax.Array:

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: masked first moments for sparse binarization.
+"""Masked first moments for sparse binarization (one tensor).
 
 Given the top-k thresholds t⁺ and t⁻ (from the histogram passes), one
 streaming HBM→VMEM pass computes, per paper Alg. 2 lines 3-4:
@@ -6,39 +6,21 @@ streaming HBM→VMEM pass computes, per paper Alg. 2 lines 3-4:
     sum⁺ = Σ x·[x ≥ t⁺]      cnt⁺ = Σ [x ≥ t⁺]
     sum⁻ = Σ x·[x ≤ −t⁻]     cnt⁻ = Σ [x ≤ −t⁻]
 
-so that μ⁺ = sum⁺/cnt⁺ and μ⁻ = −sum⁻/cnt⁻.  Output is a single (2, 2)
-block accumulated across the sequential grid: [[sum⁺, cnt⁺], [sum⁻, cnt⁻]].
-
-Padding zeros are never selected because t⁺, t⁻ > 0.
+so that μ⁺ = sum⁺/cnt⁺ and μ⁻ = −sum⁻/cnt⁻: a one-segment launch of
+:func:`repro.kernels.flat.seg_moments` with no tie set (t_hi = t, so
+every entry at or above t counts).  Padding zeros are never selected
+because t⁺, t⁻ > 0.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
+from repro.kernels.flat import seg_moments
 from repro.kernels.hist2side import DEFAULT_BM, DEFAULT_LANES, _pad_2d
-
-
-def _moments_kernel(x_ref, tpos_ref, tneg_ref, out_ref):
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    x = x_ref[...]
-    tpos = tpos_ref[0, 0]
-    tneg = tneg_ref[0, 0]
-
-    pos = x >= tpos
-    neg = x <= -tneg
-    sum_pos = jnp.sum(jnp.where(pos, x, 0.0))
-    cnt_pos = jnp.sum(jnp.where(pos, 1.0, 0.0))
-    sum_neg = jnp.sum(jnp.where(neg, x, 0.0))
-    cnt_neg = jnp.sum(jnp.where(neg, 1.0, 0.0))
-
-    out_ref[...] += jnp.array([[sum_pos, cnt_pos], [sum_neg, cnt_neg]], jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "lanes", "interpret"))
@@ -49,22 +31,15 @@ def masked_moments(
     *,
     bm: int = DEFAULT_BM,
     lanes: int = DEFAULT_LANES,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Returns (2,2) f32: [[sum⁺, cnt⁺], [sum⁻, cnt⁻]]."""
-    x, nblocks = _pad_2d(flat, bm, lanes)
-    tp = jnp.asarray(t_pos, jnp.float32).reshape(1, 1)
-    tn = jnp.asarray(t_neg, jnp.float32).reshape(1, 1)
-
-    return pl.pallas_call(
-        _moments_kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((bm, lanes), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((2, 2), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((2, 2), jnp.float32),
-        interpret=interpret,
-    )(x, tp, tn)
+    tp, tn = jnp.asarray(t_pos, jnp.float32), jnp.asarray(t_neg, jnp.float32)
+    xpad = _pad_2d(flat, bm, lanes)
+    one = jnp.ones((), jnp.float32)
+    picks = jnp.zeros((xpad.shape[0] // bm, 4), jnp.float32)
+    return seg_moments(
+        xpad, jnp.stack([tp, tp, one, one, tn, tn, one, one])[None], picks,
+        blk_starts=(0,),
+        bm=bm, lanes=lanes, interpret=interpret,
+    )[0]

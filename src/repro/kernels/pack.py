@@ -1,4 +1,4 @@
-"""Device-side Golomb position packing: fused select→pack Pallas kernels.
+"""Device-side Golomb position packing: bit streams → packed words.
 
 The host encoder (:mod:`repro.core.golomb`) produces the paper's Alg. 3
 bitstream with numpy; every byte the wire sees is therefore a host
@@ -10,10 +10,11 @@ byte production on-device:
     folds a 0/1 bit-plane buffer into packed ``uint32`` words by
     bit-shift/mask accumulation, grid-launched over word blocks exactly
     like the ``seg_*`` passes in :mod:`repro.kernels.flat`;
-  * :func:`seg_select_pack` — the fused variant: one Pallas launch per
-    (segment, row) grid that consumes the two-sided top-k MASK directly
-    and emits packed words + exact bit counts, so surviving positions
-    never materialize as an index array;
+  * :func:`seg_select_pack` — two-sided top-k MASKS straight to packed
+    words and exact bit counts: the gap stream is built from the mask by
+    XLA (cumsums + scatters, :func:`bits_from_mask`), so surviving
+    positions never materialize as an index array, and one
+    :func:`seg_packbits` launch folds every row's bits into words;
   * :func:`golomb_decode_rows` — the matching device decoder (pointer
     doubling over the next-codeword-start map, O(B·log k) fully
     parallel work), used by the sharded exchange to recover positions
@@ -29,17 +30,20 @@ Everything is static-shaped: a row with ``k`` survivors out of ``n``
 candidates needs at most ``((n - k) >> b*) + k·(1 + b*)`` stream bits
 (``Σ (d_i - 1) ≤ n - k`` bounds the unary runs), so the per-row word
 capacity — and with it the whole concatenated stream layout — is known
-at trace time.  On CPU every kernel runs with ``interpret=True`` (set
-``interpret=False`` on TPU).
+at trace time.  Interpret mode is decided by
+:func:`repro.kernels.resolve_interpret`.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def row_bit_capacity(n: int, k: int, bstar: int) -> int:
@@ -117,97 +121,80 @@ def bits_from_mask(mask: jax.Array, *, k: int, bstar: int, cap32: int) -> tuple:
 
 
 def _packbits_kernel(bits_ref, words_ref):
-    planes = bits_ref[...]  # (32, lanes) u32 bit planes of one word block
-    acc = jnp.zeros_like(planes[0])
-    for j in range(32):  # bit-shift/mask accumulation into uint32 words
-        acc = acc | (planes[j] << jnp.uint32(31 - j))
-    words_ref[...] = acc[None]
+    # bits_ref: (32, 8, lanes) u32 bit planes of one (8, lanes) word block
+    acc = bits_ref[0] << jnp.uint32(31)
+    for j in range(1, 32):  # bit-shift/mask accumulation into uint32 words
+        acc = acc | (bits_ref[j] << jnp.uint32(31 - j))
+    words_ref[...] = acc
+
+
+_WORD_ROWS = 8  # sublanes per word block
 
 
 @functools.partial(jax.jit, static_argnames=("lanes", "interpret"))
 def seg_packbits(
-    bits_pl: jax.Array, *, lanes: int = 128, interpret: bool = True
+    bits_pl: jax.Array, *, lanes: int = 128, interpret: Optional[bool] = None
 ) -> jax.Array:
     """One flat launch: bit planes → packed ``uint32`` word buffer.
 
     bits_pl: u32[32, nwords] where ``bits_pl[j, w]`` is stream bit
     ``32·w + j`` (i.e. the row-major bit buffer reshaped ``(-1, 32)`` and
-    transposed); nwords must be a multiple of ``lanes``.  Returns
-    u32[nwords] with bit ``b`` of the stream at word ``b >> 5``, bit
-    position ``31 - (b & 31)``.
+    transposed).  Returns u32[nwords] with bit ``b`` of the stream at word
+    ``b >> 5``, bit position ``31 - (b & 31)``.  The grid walks
+    ``(8, lanes)`` word blocks; the tail block is zero-padded here.
     """
     nwords = bits_pl.shape[1]
-    nblocks = nwords // lanes
+    nblocks = max(1, pl.cdiv(nwords, _WORD_ROWS * lanes))
+    nrows = nblocks * _WORD_ROWS
+    if nrows * lanes > nwords:
+        bits_pl = jnp.concatenate(
+            [bits_pl, jnp.zeros((32, nrows * lanes - nwords), bits_pl.dtype)],
+            axis=1,
+        )
     out = pl.pallas_call(
         _packbits_kernel,
         grid=(nblocks,),
-        in_specs=[pl.BlockSpec((32, lanes), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((1, lanes), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblocks, lanes), jnp.uint32),
-        interpret=interpret,
-    )(bits_pl)
-    return out.reshape(-1)
+        in_specs=[pl.BlockSpec((32, _WORD_ROWS, lanes), lambda i: (0, i, 0))],
+        out_specs=pl.BlockSpec((_WORD_ROWS, lanes), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nrows, lanes), jnp.uint32),
+        interpret=resolve_interpret(interpret),
+    )(bits_pl.reshape(32, nrows, lanes))
+    return out.reshape(-1)[:nwords]
 
 
 def pack_bit_rows(
-    bits: jax.Array, *, lanes: int = 128, interpret: bool = True
+    bits: jax.Array, *, lanes: int = 128, interpret: Optional[bool] = None
 ) -> jax.Array:
-    """Convenience wrapper: u32[..., cap32] bit rows → u32[..., cap32/32]
-    words via ONE :func:`seg_packbits` launch over the concatenation."""
+    """u32[..., cap32] bit rows → u32[..., cap32/32] words via ONE
+    :func:`seg_packbits` launch over the concatenation (``cap32`` is a
+    multiple of 32)."""
     cap32 = bits.shape[-1]
-    flat = bits.reshape(-1)
-    pad = -flat.shape[0] % (32 * lanes)
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-    planes = flat.reshape(-1, 32).T
+    planes = bits.reshape(-1, 32).T
     words = seg_packbits(planes, lanes=lanes, interpret=interpret)
-    nw = bits.size // 32 if bits.size else 0
-    return words[:nw].reshape(bits.shape[:-1] + (cap32 // 32,))
+    return words.reshape(bits.shape[:-1] + (cap32 // 32,))
 
 
-# ------------------------------------------------- fused select→pack pass
-
-
-def _select_pack_kernel(mask_ref, words_ref, nbits_ref, *, k, bstar, cap32):
-    m = mask_ref[0, :]
-    bits, nbits = bits_from_mask(m, k=k, bstar=bstar, cap32=cap32)
-    grouped = bits.reshape(-1, 32)
-    acc = jnp.zeros((grouped.shape[0],), jnp.uint32)
-    for j in range(32):
-        acc = acc | (grouped[:, j] << jnp.uint32(31 - j))
-    words_ref[...] = acc[None]
-    nbits_ref[...] = nbits[None, None]
+# ------------------------------------------------------ select→pack pass
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bstar", "interpret"))
 def seg_select_pack(
-    mask: jax.Array, *, k: int, bstar: int, interpret: bool = True
+    mask: jax.Array, *, k: int, bstar: int, interpret: Optional[bool] = None
 ) -> tuple:
-    """Fused select→pack: two-sided top-k masks straight to packed words.
+    """Select→pack: two-sided top-k masks straight to packed words.
 
     mask: bool/int[rows, n] with exactly ``k`` selected slots per row.
-    One grid step per row builds the row's Golomb stream from the mask
-    (no index array) and folds it into ``uint32`` words in-kernel.
+    Each row's Golomb stream is built from its mask (no index array), and
+    one :func:`seg_packbits` launch folds every row into ``uint32`` words.
     Returns ``(words u32[rows, W], nbits i32[rows])`` with
     ``W = row_words(n, k, b*)``.
     """
-    rows, n = mask.shape
+    n = mask.shape[1]
     cap32 = 32 * row_words(n, k, bstar)
-    words, nbits = pl.pallas_call(
-        functools.partial(_select_pack_kernel, k=k, bstar=bstar, cap32=cap32),
-        grid=(rows,),
-        in_specs=[pl.BlockSpec((1, n), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((1, cap32 // 32), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows, cap32 // 32), jnp.uint32),
-            jax.ShapeDtypeStruct((rows, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(mask.astype(jnp.int32))
-    return words, nbits[:, 0]
+    bits, nbits = jax.vmap(
+        lambda m: bits_from_mask(m, k=k, bstar=bstar, cap32=cap32)
+    )(mask)
+    return pack_bit_rows(bits, interpret=interpret), nbits
 
 
 # ------------------------------------------------------------ device decode
@@ -249,12 +236,9 @@ def _decode_row(words: jax.Array, *, k: int, bstar: int) -> jax.Array:
     return (jnp.cumsum(dm1 + 1) - 1).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "bstar", "interpret"))
-def golomb_decode_rows(
-    words: jax.Array, *, k: int, bstar: int, interpret: bool = True
-) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("k", "bstar"))
+def golomb_decode_rows(words: jax.Array, *, k: int, bstar: int) -> jax.Array:
     """u32[..., W] packed streams → i32[..., k] ascending positions."""
-    del interpret  # decode is pure jnp; kept for call-site symmetry
     fn = functools.partial(_decode_row, k=k, bstar=bstar)
     lead = words.shape[:-1]
     out = jax.vmap(fn)(words.reshape((-1,) + words.shape[-1:]))

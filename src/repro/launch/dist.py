@@ -301,9 +301,9 @@ def build_dist_train(
     same as PR 3's single-device fast path.
 
     ``flat_engine`` — 'exact' (default; two-sided per-row top-k) or
-    'hist' (the segment-aware Pallas passes, approximate survivor
-    counts, dense pmean exchange); 'hist' needs an all-SBC policy and an
-    active fast path.
+    'hist' (the segment-aware Pallas passes, histogram thresholds with
+    exactly k survivors per (leaf, shard), dense pmean exchange); 'hist'
+    needs an all-SBC policy and an active fast path.
 
     ``measure`` — every round, additionally emit client 0's transmitted
     ΔW* (``metrics['own_client0']`` — explicitly a CLIENT-0 SAMPLE, not
@@ -492,10 +492,11 @@ def build_dist_train(
             # client 0's transmitted ΔW*, for host-side wire metering
             metrics["own_client0"] = jax.tree.map(lambda o: o[0], own_tree)
             if device_pack:
-                # exact per-(client, shard, row) packed wire bits + client
-                # 0's packed word buffer (byte-identity tests read it)
+                # every client's upload: packed words, exact per-(client,
+                # shard, row) bits, per-row μ (the host can decode it)
+                metrics["packed_words"] = packed[0]
                 metrics["packed_nbits"] = packed[1]
-                metrics["packed_words_client0"] = packed[0][0]
+                metrics["packed_mu"] = packed[2]
         return (
             {"params": new_params, "opt": opt_states, "residual": new_residual},
             metrics,
@@ -692,9 +693,11 @@ def build_parser():
 
 
 def main(argv=None):
+    from repro.paths import use_compile_cache
     from repro.run.build import build_run
     from repro.run.flags import spec_from_args
 
+    use_compile_cache()
     args = build_parser().parse_args(argv)
     spec = spec_from_args(args, backend="gspmd")
     run = build_run(spec)

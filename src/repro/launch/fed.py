@@ -33,6 +33,7 @@ import time
 import jax
 
 from repro.core.policy import DENSE_SMALL_PATTERN
+from repro.paths import use_compile_cache
 from repro.run.build import build_run
 from repro.run.flags import add_run_flags, spec_from_args
 from repro.run.presets import fed_tiny_config  # noqa: F401 (re-export)
@@ -58,6 +59,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None):
+    use_compile_cache()
     args = build_parser().parse_args(argv)
     spec = spec_from_args(args, backend="fed")
     run = build_run(spec)

@@ -11,25 +11,24 @@ from __future__ import annotations
 import jax
 
 
-def _make_mesh(shape, axes):
-    # pin Auto axis types where the API exists: the framework relies on
-    # GSPMD sharding propagation (jax v0.9 flips the default to Explicit);
-    # older jax (< 0.6) has no AxisType and Auto is already the only mode
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """A device mesh with Auto axis types: the framework relies on GSPMD
+    sharding propagation, and a bare ``jax.make_mesh`` gives Explicit
+    axes."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """A 1-device mesh for CPU tests of the distributed code paths."""
-    return _make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def axis_sizes(mesh) -> dict[str, int]:

@@ -28,6 +28,7 @@ import jax
 
 from repro.checkpoint import save_pytree
 from repro.core.baselines import dgc_policy  # noqa: F401 (registration)
+from repro.paths import use_compile_cache
 from repro.run.build import build_run, lr_schedule  # noqa: F401 (re-export)
 from repro.run.flags import add_run_flags, spec_from_args
 from repro.run.presets import build_preset, lm_100m_config  # noqa: F401
@@ -50,6 +51,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None):
+    use_compile_cache()
     args = build_parser().parse_args(argv)
     spec = spec_from_args(args, backend="local")
     run = build_run(spec)

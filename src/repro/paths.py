@@ -1,4 +1,5 @@
-"""One repo-root resolver for every module that writes committed artifacts.
+"""One repo-root resolver for every module that writes artifacts, and the
+one place that points JAX's persistent compile cache.
 
 ``benchmarks/common.py`` and ``repro.launch.dryrun`` used to each carry
 their own ``os.path.dirname(...)`` chains relative to ``__file__`` — path
@@ -41,3 +42,20 @@ def experiments_dir(*parts: str, create: bool = False) -> str:
     if create:
         os.makedirs(path, exist_ok=True)
     return path
+
+
+def use_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives at the fixed
+    ``<repo>/.jax_cache`` (gitignored) — a fixed path, so that the next
+    process finds the entries again.
+    """
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    jax.config.update(
+        "jax_compilation_cache_dir", os.path.join(repo_root(), ".jax_cache")
+    )

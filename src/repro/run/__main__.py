@@ -15,11 +15,13 @@ import time
 
 import jax
 
+from repro.paths import use_compile_cache
 from repro.run.build import build_run
 from repro.run.flags import build_parser, spec_from_args
 
 
 def main(argv=None):
+    use_compile_cache()
     args = build_parser().parse_args(argv)
     spec = spec_from_args(args)
     run = build_run(spec)

@@ -358,7 +358,9 @@ class GspmdRun(Run):
     def init(self, rng: Optional[jax.Array] = None):
         if rng is None:
             rng = jax.random.PRNGKey(self.spec.seed)
-        return self.fns.init_state(rng)
+        # placed as the step expects it, so the first round compiles the
+        # same program as every later one
+        return jax.device_put(self.fns.init_state(rng), self.fns.state_shardings)
 
     def _batch(self, round_idx: int) -> PyTree:
         ids = np.arange(self.n_clients)
@@ -379,7 +381,8 @@ class GspmdRun(Run):
         if self.spec.measure_wire:
             own_client0 = m.pop("own_client0")
             packed_nbits = m.pop("packed_nbits", None)
-            m.pop("packed_words_client0", None)
+            m.pop("packed_words", None)
+            m.pop("packed_mu", None)
             m["measured_bits_per_client"] = self.channel.record_round(
                 round_idx, own_client0=own_client0, packed_nbits=packed_nbits
             )

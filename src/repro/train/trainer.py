@@ -129,11 +129,7 @@ class DSGDTrainer:
 
     # ------------------------------------------------------------- one round
 
-    @partial(
-        jax.jit,
-        static_argnames=("self", "n_delay", "sparsity", "return_compressed"),
-    )
-    def round_step(
+    def _round_step(
         self,
         state: TrainState,
         batch: PyTree,  # (clients, n_delay, per_client_batch, ...)
@@ -199,6 +195,16 @@ class DSGDTrainer:
             # client 0's compressed tree, for host-side wire measurement
             return new_state, metrics, ex.compressed0
         return new_state, metrics
+
+    # the round consumes ``state``: its buffers are reused for the new
+    # state, so a round holds one copy of the per-client optimizer and
+    # residual state, not two.  A caller that still needs the old state
+    # copies it before stepping.
+    round_step = partial(
+        jax.jit,
+        static_argnames=("self", "n_delay", "sparsity", "return_compressed"),
+        donate_argnames=("state",),
+    )(_round_step)
 
     # --------------------------------------------------------------- fitting
 

@@ -4,30 +4,24 @@ CPU device; only launch/dryrun.py fakes 512 devices (in its own process).
 The suite is XLA-compile dominated, so two layers of caching keep wall time
 down (ISSUE 2 satellite):
 
-  * a persistent on-disk XLA compilation cache (``tests/.jax_cache``,
-    gitignored) — repeat local runs skip almost every compile;
+  * a persistent on-disk XLA compilation cache
+    (:func:`repro.paths.use_compile_cache`: ``JAX_COMPILATION_CACHE_DIR``
+    or ``<repo>/.jax_cache``) — repeat local runs skip almost every compile;
   * session-scoped model/param builders (``arch_setup``, ``lm_setup``) —
     each reduced architecture is built and initialized ONCE and shared by
     every test that exercises it, so ``model.init``/``loss_fn`` jit caches
     hit across tests instead of recompiling per test function.
 """
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.configs.base import ModelConfig
+from repro.paths import use_compile_cache
 
-try:  # persistent compile cache: first run pays, reruns are fast
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)), ".jax_cache"),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:  # older jax without the flags — caching is best-effort
-    pass
+use_compile_cache()  # first run pays, reruns are fast
 
 
 @pytest.fixture(scope="session")

@@ -20,8 +20,9 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.launch.dist import client_topology, make_dist_train
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 
 
 def tiny(client_mode):

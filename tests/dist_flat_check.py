@@ -26,23 +26,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # reuse the suite's persistent compile cache (conftest.py does the
-    # same for in-process tests; this child pays the dominant compiles)
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)), ".jax_cache"),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:  # pragma: no cover - older jax without the flags
-    pass
+from repro.paths import use_compile_cache
+
+use_compile_cache()  # the suite's cache (conftest.py does the same)
 
 from repro.configs.base import ModelConfig
 from repro.core.codec import make_codec
 from repro.core.policy import DENSE_SMALL_PATTERN, CompressionPolicy, PolicyRule
 from repro.launch.dist import client_topology, make_dist_train
+from repro.launch.mesh import make_mesh
 from repro.models.model import build_model
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 
 
 def tiny(client_mode):

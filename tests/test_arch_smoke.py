@@ -72,6 +72,7 @@ class TestArchSmoke:
             lambda x: jnp.broadcast_to(x, (2, 1) + x.shape),
             _batch_for(cfg, rng),
         )
+        params0 = jax.tree.map(jnp.copy, state.params)  # the round donates state
         new_state, m = trainer.round_step(state, batch, n_delay=1, sparsity=0.05)
         assert bool(jnp.isfinite(m["loss"]))
         assert float(m["bits_per_client"]) < float(m["bits_dense"])
@@ -80,7 +81,7 @@ class TestArchSmoke:
         moved = any(
             bool(jnp.any(a != b))
             for a, b in zip(jax.tree.leaves(new_state.params),
-                            jax.tree.leaves(state.params))
+                            jax.tree.leaves(params0))
         )
         assert moved, f"{arch}: no parameter moved after a round"
 

@@ -76,12 +76,13 @@ def test_resume_mid_run_is_bit_identical(tmp_path, lm_setup):
     mid = run_rounds(trainer, batch_fn, state, rates, 0, 2)
     path = str(tmp_path / "mid.npz")
     save_train_state(path, mid)
-    uninterrupted = run_rounds(trainer, batch_fn, mid, rates, 2, 2)
 
     like = trainer.init(jax.random.PRNGKey(7))  # template only
     restored = restore_train_state(path, like)
     assert_state_bitwise(restored, mid)  # the checkpoint itself is lossless
     assert int(restored.round) == 2
+    # each round donates its state, so mid is stepped only after the check
+    uninterrupted = run_rounds(trainer, batch_fn, mid, rates, 2, 2)
     resumed = run_rounds(trainer, batch_fn, restored, rates, 2, 2)
 
     assert_state_bitwise(resumed, uninterrupted)

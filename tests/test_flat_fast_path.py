@@ -30,7 +30,12 @@ from repro.core.policy import (
 )
 from repro.core.wire import wire_for
 from repro.kernels import ops, ref
-from repro.kernels.flat import seg_binarize_apply, seg_hist2side, seg_moments
+from repro.kernels.flat import (
+    seg_binarize_apply,
+    seg_hist2side,
+    seg_moments,
+    seg_tier_counts,
+)
 from repro.kernels.hist2side import SPAN_OCTAVES, hist2side
 from repro.kernels.moments import masked_moments
 
@@ -218,23 +223,21 @@ class TestSegKernels:
             segs.append((off, s, x))
             off += max(1, -(-s // per_block)) * per_block
         xpad = np.zeros((off,), np.float32)
-        seg_of_block = np.zeros((off // per_block,), np.int32)
-        for i, (o, s, x) in enumerate(segs):
+        for o, s, x in segs:
             xpad[o:o + s] = x
-            seg_of_block[o // per_block:(o + s - 1) // per_block + 1] = i
-        return segs, xpad.reshape(-1, LANES), seg_of_block
+        blk_starts = tuple(o // per_block for o, _, _ in segs)
+        return segs, xpad.reshape(-1, LANES), blk_starts
 
     def test_seg_hist2side_matches_per_leaf_and_ref(self):
-        segs, xpad, sob = self._layout()
+        segs, xpad, starts = self._layout()
         nbins = 32
         los = np.array([max(np.abs(x).max(), 1e-30) * 2.0**-SPAN_OCTAVES
                         for _, _, x in segs], np.float32)
         his = np.array([max(np.abs(x).max(), 1e-30) * 1.0001
                         for _, _, x in segs], np.float32)
-        params = np.stack([sob.astype(np.float32), los[sob], his[sob],
-                           los[sob], his[sob]], axis=1)
+        params = np.stack([los, his, los, his], axis=1)
         got = seg_hist2side(jnp.asarray(xpad), jnp.asarray(params),
-                            nseg=len(segs), nbins=nbins, bm=BM, lanes=LANES)
+                            blk_starts=starts, nbins=nbins, bm=BM, lanes=LANES)
         for i, (_, _, x) in enumerate(segs):
             want_leaf = hist2side(jnp.asarray(x), los[i], his[i],
                                   nbins=nbins, bm=BM, lanes=LANES)
@@ -242,34 +245,98 @@ class TestSegKernels:
             np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(want_leaf))
             np.testing.assert_allclose(np.asarray(got[i]), np.asarray(want_ref))
 
+    # per segment (t⁺, t_hi⁺, t⁻, t_hi⁻): a real tie bucket on each side,
+    # an empty one (t_hi = t), and the swapped form (t_hi = ∞)
+    TIERS = np.array([[0.7, 1.5, 0.9, 1.6], [0.5, 0.5, 0.6, 0.6],
+                      [0.1, 0.2, 0.1, 0.2], [0.3, np.inf, 0.2, 1.0]],
+                     np.float32)
+
+    STRIDES = np.array([[1, 3], [2, 1], [7, 2], [1, 1]], np.float32)
+
+    def _picks(self, segs, ncols, seed):
+        """Random per-block (j0, lim) pairs, some past a block's ties."""
+        per_block = BM * LANES
+        nblocks = sum(max(1, -(-s // per_block)) for _, s, _ in segs)
+        rng = np.random.default_rng(seed)
+        cols = []
+        for _ in range(ncols // 2):
+            cols += [rng.integers(0, 40, nblocks), rng.integers(0, 400, nblocks)]
+        return np.stack(cols, axis=1).astype(np.float32)
+
+    def _table(self):
+        """(nseg, 8) rows (t⁺, t_hi⁺, s⁺, 1/s⁺, t⁻, t_hi⁻, s⁻, 1/s⁻)."""
+        t, st = self.TIERS, self.STRIDES
+        return np.stack([t[:, 0], t[:, 1], st[:, 0], 1 / st[:, 0],
+                         t[:, 2], t[:, 3], st[:, 1], 1 / st[:, 1]], axis=1)
+
+    def _own(self, segs, i, picks):
+        """Segment i alone, as a one-segment launch sees it."""
+        per_block = BM * LANES
+        o, s, x = segs[i]
+        b0 = o // per_block
+        nb = max(1, -(-s // per_block))
+        xpad = np.zeros((nb * per_block,), np.float32)
+        xpad[:s] = x
+        return xpad.reshape(-1, LANES), picks[b0:b0 + nb], b0, nb
+
+    def _ties(self, i, picks):
+        t_pos, th_pos, t_neg, th_neg = self.TIERS[i]
+        s_pos, s_neg = self.STRIDES[i]
+        return (t_pos, t_neg), (th_pos, int(s_pos), th_neg, int(s_neg), picks)
+
+    def test_seg_tier_counts_matches_ref(self):
+        segs, xpad, starts = self._layout(3)
+        got = np.asarray(seg_tier_counts(
+            jnp.asarray(xpad), jnp.asarray(self.TIERS), blk_starts=starts,
+            bm=BM, lanes=LANES))
+        for i, (o, s, x) in enumerate(segs):
+            b0 = o // (BM * LANES)
+            want = np.asarray(ref.tier_counts_ref(
+                jnp.asarray(x), *self.TIERS[i], block=BM * LANES))
+            np.testing.assert_array_equal(got[b0:b0 + len(want)], want)
+        assert got.dtype == np.int32 and got.shape == (xpad.shape[0] // BM, 4)
+
     def test_seg_moments_matches_per_leaf_and_ref(self):
-        segs, xpad, sob = self._layout(1)
-        tp = np.array([0.7, 0.5, 0.1, 0.3], np.float32)
-        tn = np.array([0.9, 0.6, 0.1, 0.2], np.float32)
-        params = np.stack([sob.astype(np.float32), tp[sob], tn[sob]], axis=1)
-        got = seg_moments(jnp.asarray(xpad), jnp.asarray(params),
-                          nseg=len(segs), bm=BM, lanes=LANES)
+        segs, xpad, starts = self._layout(1)
+        picks = self._picks(segs, 4, 1)
+        table = self._table()
+        got = seg_moments(jnp.asarray(xpad), jnp.asarray(table),
+                          jnp.asarray(picks), blk_starts=starts, bm=BM,
+                          lanes=LANES)
         for i, (_, _, x) in enumerate(segs):
-            want_leaf = masked_moments(jnp.asarray(x), tp[i], tn[i],
-                                       bm=BM, lanes=LANES)
-            want_ref = ref.masked_moments_ref(jnp.asarray(x), tp[i], tn[i])
+            own, own_picks, _, _ = self._own(segs, i, picks)
+            want_leaf = seg_moments(jnp.asarray(own), jnp.asarray(table[i:i + 1]),
+                                    jnp.asarray(own_picks), blk_starts=(0,),
+                                    bm=BM, lanes=LANES)[0]
+            (t_pos, t_neg), ties = self._ties(i, own_picks)
+            want_ref = ref.masked_moments_ref(jnp.asarray(x), t_pos, t_neg,
+                                              ties, block=BM * LANES)
             np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(want_leaf))
             np.testing.assert_allclose(np.asarray(got[i]), np.asarray(want_ref),
                                        rtol=1e-4)
+        # the per-leaf wrapper is the no-tie launch
+        x = jnp.asarray(segs[0][2])
+        np.testing.assert_allclose(
+            np.asarray(masked_moments(x, 0.7, 0.9, bm=BM, lanes=LANES)),
+            np.asarray(ref.masked_moments_ref(x, 0.7, 0.9)), rtol=1e-4)
 
     def test_seg_binarize_apply_matches_ref(self):
-        segs, xpad, sob = self._layout(2)
-        tp = np.array([0.5, 0.4, 0.1, 0.2], np.float32)
-        tn = np.array([0.6, 0.5, 0.1, 0.3], np.float32)
+        segs, xpad, starts = self._layout(2)
         mu = np.array([0.55, -0.45, 0.2, 0.1], np.float32)
         side = np.array([1.0, 0.0, 1.0, 0.0], np.float32)
-        params = np.stack([tp[sob], tn[sob], mu[sob], side[sob]], axis=1)
+        params = np.concatenate([self._table(), mu[:, None], side[:, None]],
+                                axis=1)
+        picks = self._picks(segs, 2, 2)
         out, res = seg_binarize_apply(jnp.asarray(xpad), jnp.asarray(params),
+                                      jnp.asarray(picks), blk_starts=starts,
                                       bm=BM, lanes=LANES)
         out, res = np.asarray(out).reshape(-1), np.asarray(res).reshape(-1)
         for i, (o, s, x) in enumerate(segs):
+            _, own_picks, _, _ = self._own(segs, i, picks)
+            (t_pos, t_neg), ties = self._ties(i, own_picks)
             w_out, w_res = ref.binarize_apply_ref(
-                jnp.asarray(x), tp[i], tn[i], mu[i], side[i])
+                jnp.asarray(x), t_pos, t_neg, mu[i], side[i], ties,
+                block=BM * LANES)
             np.testing.assert_array_equal(out[o:o + s], np.asarray(w_out))
             np.testing.assert_array_equal(res[o:o + s], np.asarray(w_res))
         # padding region: ΔW* = 0 and R = 0 (caller slices it off)
@@ -281,9 +348,10 @@ class TestSegKernels:
 
 class TestHistEngine:
     def test_matches_per_leaf_sbc_compress_hist(self):
-        """Flat hist pipeline == per-leaf kernel pipeline per segment:
-        identical block partition → identical accumulation order → μ,
-        counts, ΔW*, and residuals match bit for bit."""
+        """Flat hist pipeline == per-leaf ``ops.sbc_compress_hist`` per
+        segment: identical block partition → identical accumulation order →
+        μ, counts, ΔW*, and residuals match bit for bit; every segment
+        keeps exactly k."""
         params = {"a": jnp.zeros((70, 80)), "b": jnp.zeros((333,)),
                   "c": jnp.zeros((17,)), "z": jnp.zeros((50,))}
         delta = jax.tree.map(
@@ -303,39 +371,19 @@ class TestHistEngine:
         )
 
         from repro.core.golomb import expected_position_bits
-        from repro.kernels.binarize_apply import binarize_apply
-        from repro.kernels.hist2side import bucket_lower_edges
 
         for i, name in enumerate(["a", "b", "c", "z"]):
             x = delta[name].reshape(-1).astype(jnp.float32)
             n = x.shape[0]
             k = max(1, min(n, int(round(rates[i] * n))))
-            scale = jnp.max(jnp.abs(x)) + 1e-30
-            lo0, hi0 = scale * 2.0**-SPAN_OCTAVES, scale * 1.0001
-            h1 = hist2side(x, lo0, hi0, nbins=32, bm=BM, lanes=LANES)
-            e0 = bucket_lower_edges(lo0, hi0, 32)
-            kf = jnp.asarray(k, jnp.float32)
-            lo_p, hi_p, ab_p = ops._side_threshold(h1[0], e0, kf)
-            lo_n, hi_n, ab_n = ops._side_threshold(h1[1], e0, kf)
-            h2 = hist2side(x, jnp.stack([lo_p, lo_n]), jnp.stack([hi_p, hi_n]),
-                           nbins=32, bm=BM, lanes=LANES)
-            t_pos, _, _ = ops._side_threshold(
-                h2[0], bucket_lower_edges(lo_p, hi_p, 32), kf - ab_p)
-            t_neg, _, _ = ops._side_threshold(
-                h2[1], bucket_lower_edges(lo_n, hi_n, 32), kf - ab_n)
-            mom = masked_moments(x, t_pos, t_neg, bm=BM, lanes=LANES)
-            mu_pos = mom[0, 0] / jnp.maximum(mom[0, 1], 1.0)
-            mu_neg = -mom[1, 0] / jnp.maximum(mom[1, 1], 1.0)
-            win = mu_pos > mu_neg
-            mu = jnp.where(win, mu_pos, -mu_neg)
-            cnt = jnp.where(win, mom[0, 1], mom[1, 1])
-            out, _ = binarize_apply(x, t_pos, t_neg, mu, win.astype(jnp.float32),
-                                    bm=BM, lanes=LANES)
+            want = ops.sbc_compress_hist(x, p=rates[i], nbins=32)
             assert np.asarray(dense_tree[name]).reshape(-1).tobytes() == \
-                np.asarray(out).tobytes()
-            assert np.asarray(stats["mu"][i]).tobytes() == np.asarray(mu).tobytes()
-            assert float(stats["count"][i]) == float(cnt)
-            want_bits = float(cnt) * expected_position_bits(rates[i]) + 32.0
+                np.asarray(want.delta_star).tobytes()
+            assert np.asarray(stats["mu"][i]).tobytes() == \
+                np.asarray(want.mean).tobytes()
+            assert float(stats["count"][i]) == float(want.count)
+            assert float(stats["count"][i]) == (k if name != "z" else 0)
+            want_bits = float(want.count) * expected_position_bits(rates[i]) + 32.0
             np.testing.assert_allclose(float(stats["nbits"][i]), want_bits,
                                        rtol=1e-5)
 
@@ -344,6 +392,45 @@ class TestHistEngine:
         recon = space.flatten(dense_tree) + new_state.residual
         np.testing.assert_allclose(np.asarray(acc), np.asarray(recon),
                                    rtol=1e-6, atol=1e-7)
+
+    @pytest.mark.parametrize("scale_hi", [1.0, 3.0])
+    def test_ties_keep_exactly_k_spread_over_the_segment(self, scale_hi):
+        """Adam's first step makes |ΔW| equal (≈ lr) almost everywhere: the
+        whole tie bucket is far larger than k.  Every segment still keeps
+        exactly k, μ is the mean of exactly the kept entries, every kept
+        entry is at least as large as every dropped one of its side up to
+        the bucket, and the picks spread over the segment (mean gap ≈ n/k)
+        instead of clustering at its start."""
+        rng = np.random.default_rng(7)
+        sizes = {"a": (300, 40), "b": (9000,), "c": (700,)}
+        delta = {}
+        for name, shape in sizes.items():
+            v = np.full(shape, 1e-3, np.float32)
+            v *= np.sign(rng.standard_normal(shape)).astype(np.float32)
+            flat = v.reshape(-1)
+            flat[rng.integers(0, flat.size, 5)] *= scale_hi
+            delta[name] = jnp.asarray(v)
+        params = jax.tree.map(jnp.zeros_like, delta)
+        pol = dataclasses.replace(
+            CompressionPolicy.single(get_compressor("sbc").codec), fast=True
+        )
+        res = pol.resolve(params)
+        space = flatmod.FlatParamSpace.for_resolved(res, params, bm=BM, lanes=LANES)
+        rates = res.rates(0.01, 0)
+        dense_tree, _, stats = space.compress_hist(
+            delta, res.init_state(params), rates)
+        for i, name in enumerate(sorted(sizes)):
+            x = np.asarray(delta[name]).reshape(-1)
+            out = np.asarray(dense_tree[name]).reshape(-1)
+            k = max(1, min(x.size, int(round(rates[i] * x.size))))
+            kept = np.flatnonzero(out)
+            assert kept.size == k == float(stats["count"][i])
+            mu = float(stats["mu"][i])
+            np.testing.assert_allclose(mu, x[kept].mean(), rtol=1e-6)
+            sign = np.sign(mu)
+            dropped = np.setdiff1d(np.flatnonzero(np.sign(x) == sign), kept)
+            assert np.abs(x[kept]).min() >= np.abs(x[dropped]).max()
+            assert np.diff(kept).mean() > 0.5 * x.size / k
 
     def test_rejects_non_sbc_policies(self):
         params, delta = rand_delta()

@@ -83,12 +83,13 @@ class TestFullPipeline:
     @pytest.mark.parametrize("p", [0.05, 0.01])
     def test_hist_close_to_exact(self, n, p):
         """Histogram-threshold SBC ≈ exact top-k SBC (the paper's Alg. 2):
-        survivor count within ±2% of k, means within 2%."""
+        exactly k survivors, means within 2%."""
         x = _x(5, n)
         got = ops.sbc_compress_hist(x, p=p)
         want = ops.sbc_compress_exact(x, p=p)
         k = max(1, round(p * n))
-        assert abs(float(got.count) - k) <= max(2, 0.02 * k)
+        assert float(got.count) == k
+        assert int(jnp.sum(got.delta_star != 0)) == k
         assert abs(float(got.mean) - float(want.mean)) <= 0.02 * abs(float(want.mean))
 
     def test_exact_matches_oracle(self):
@@ -115,6 +116,10 @@ class TestFullPipeline:
         x = jnp.ones((1000,))
         out = ops.sbc_compress_hist(x, p=0.01)
         assert bool(jnp.all(jnp.isfinite(out.delta_star)))
+        # every entry ties: exactly k are kept, every 100th by position
+        kept = np.flatnonzero(np.asarray(out.delta_star))
+        np.testing.assert_array_equal(kept, np.arange(0, 1000, 100))
+        assert float(out.mean) == 1.0
 
     def test_dense_to_sparse_extraction(self):
         x = jnp.zeros((100,)).at[jnp.array([3, 50, 99])].set(2.5)

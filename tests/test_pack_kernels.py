@@ -273,7 +273,7 @@ def test_sharded_space_pack_matches_host_per_row():
     ]
     res = jnp.zeros((space.n_pad,), jnp.float32)
     mean0, own0, nr0 = jax.jit(space.exchange_local)(bodies, res)
-    mean1, own1, nr1, words, nbits = space.exchange_local(
+    mean1, own1, nr1, words, nbits, _ = space.exchange_local(
         bodies, res, device_pack=True
     )
     for a, c in ((mean0, mean1), (own0, own1), (nr0, nr1)):

@@ -127,6 +127,23 @@ class TestData:
         np.testing.assert_array_equal(np.asarray(b1["labels"][:, :-1]),
                                       np.asarray(b1["tokens"][:, 1:]))
 
+    def test_markov_task_large_vocab_draws_rows_lazily(self):
+        """Above the dense-table size the chain's rows come from per-token
+        keys: still one fixed chain (deterministic, in range, batched
+        sampling identical to per-pair sampling), no V×V table."""
+        from repro.data.synthetic import DENSE_CHAIN_MAX_VOCAB
+
+        vocab = DENSE_CHAIN_MAX_VOCAB + 1
+        task = make_lm_task(vocab=vocab, batch=2, seq_len=8, temperature=0.5)
+        b1, b2 = task.sample(3, 1), task.sample(3, 1)
+        np.testing.assert_array_equal(np.asarray(b1["tokens"]),
+                                      np.asarray(b2["tokens"]))
+        toks = np.asarray(b1["tokens"])
+        assert toks.min() >= 0 and toks.max() < vocab
+        assert 0.0 < task.entropy_floor < np.log(vocab)
+        many = task.sample_many([3, 0], [1, 2])
+        np.testing.assert_array_equal(np.asarray(many["tokens"][0]), toks)
+
     def test_affine_task_is_deterministic_sequence(self):
         task = make_lm_task(vocab=97, batch=2, seq_len=8, kind="affine")
         b = task.sample(0, 0)

@@ -56,14 +56,15 @@ class TestConvergence:
         task = make_lm_task(vocab=cfg.vocab_size, batch=8, seq_len=32)
         tr = _trainer(model, "none", opt="sgd", clients=1, lr=0.1)
         state = tr.init(rng)
+        params0 = jax.tree.map(jnp.copy, state.params)  # the round donates state
         batch = client_batches(task, 1, 1)(0)
         new_state, m = tr.round_step(state, batch, n_delay=1, sparsity=1.0)
 
         # manual SGD step
         loss, g = jax.value_and_grad(model.loss_fn)(
-            state.params, jax.tree.map(lambda x: x[0, 0], batch)
+            params0, jax.tree.map(lambda x: x[0, 0], batch)
         )
-        manual = jax.tree.map(lambda p, gg: p - 0.1 * gg, state.params, g)
+        manual = jax.tree.map(lambda p, gg: p - 0.1 * gg, params0, g)
         for a, b in zip(jax.tree.leaves(new_state.params), jax.tree.leaves(manual)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
                                        atol=2e-6)
