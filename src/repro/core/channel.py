@@ -60,6 +60,7 @@ from repro.core.api import Compressor
 from repro.core.golomb import encode_positions, expected_position_bits
 from repro.core.ledger import BandwidthLedger, RoundRecord
 from repro.obs import NULL_TELEMETRY
+from repro.obs.scopes import scope
 from repro.core.policy import CompressionPolicy, CompressorState, ResolvedPolicy
 from repro.core.wire import Wire, wire_for
 
@@ -239,24 +240,27 @@ class LocalVmapChannel:
         (traced; called inside the trainer's jitted round)."""
 
         def compress_one(delta, comp_state):
-            ctree, dense, new_state = self.compressor.compress(
-                delta, comp_state, rates
-            )
-            bits = self.compressor.total_bits(ctree)
+            # the selector's own scope sits inside (Codec.compress_leaf)
+            with scope("quantize"):
+                ctree, dense, new_state = self.compressor.compress(
+                    delta, comp_state, rates
+                )
+                bits = self.compressor.total_bits(ctree)
             return ctree, dense, new_state, bits
 
         ctrees, dense, new_state, bits = jax.vmap(compress_one)(deltas, state)
-        mean_delta = jax.tree.map(lambda d: jnp.mean(d, axis=0), dense)
-        comp0 = (
-            jax.tree.map(lambda x: x[0], ctrees) if return_compressed else None
-        )
-        return LocalExchange(
-            mean_delta=mean_delta,
-            transmitted=dense,
-            state=new_state,
-            bits_per_client=jnp.mean(bits),
-            compressed0=comp0,
-        )
+        with scope("apply"):
+            mean_delta = jax.tree.map(lambda d: jnp.mean(d, axis=0), dense)
+            comp0 = (
+                jax.tree.map(lambda x: x[0], ctrees) if return_compressed else None
+            )
+            return LocalExchange(
+                mean_delta=mean_delta,
+                transmitted=dense,
+                state=new_state,
+                bits_per_client=jnp.mean(bits),
+                compressed0=comp0,
+            )
 
     def bits(self, params: PyTree, rates: Tuple[float, ...],
              n_delay: int = 1) -> ChannelBits:
@@ -291,9 +295,14 @@ class LocalVmapChannel:
         With ``device_pack`` the Golomb position streams are produced by
         the fused select→pack Pallas kernel (byte-identical to the host
         encoder — held by tests/test_channel_parity.py)."""
+        # one blocking copy per array, in the order the pack reads them
+        arrays = jax.tree.leaves(compressed0)
+        with self.telemetry.span("fetch", round=round_idx, arrays=len(arrays),
+                                 bytes=sum(x.nbytes for x in arrays)):
+            host0 = jax.tree.map(np.asarray, compressed0)
         with self.telemetry.span("encode", round=round_idx, client=0):
             w = self.wire(params, rate, round_idx)
-            blob, bits = w.pack_with_bits(compressed0, device_pack=device_pack)
+            blob, bits = w.pack_with_bits(host0, device_pack=device_pack)
         measured = float(bits)
         up_bytes = len(blob) * self.n_clients
         self.ledger.record_up(
@@ -325,40 +334,45 @@ def _sbc_local(acc_flat: jax.Array, p: float, client_axes, n_clients: int,
     k = max(1, min(n_loc, int(round(p * n_loc))))
 
     def one_layer(_, x_row):
-        x = x_row.astype(jnp.float32)
-        val_pos, idx_pos = jax.lax.top_k(x, k)
-        val_neg, idx_neg = jax.lax.top_k(-x, k)
-        mu_pos, mu_neg = jnp.mean(val_pos), jnp.mean(val_neg)
-        pos_wins = mu_pos > mu_neg
-        idx = jnp.where(pos_wins, idx_pos, idx_neg).astype(jnp.int32)
-        mu = jnp.where(pos_wins, mu_pos, -mu_neg).astype(jnp.float32)
-        own_row = jnp.zeros((n_loc,), out_dtype).at[idx].set(mu.astype(out_dtype))
+        with scope("select"):
+            x = x_row.astype(jnp.float32)
+            val_pos, idx_pos = jax.lax.top_k(x, k)
+            val_neg, idx_neg = jax.lax.top_k(-x, k)
+            mu_pos, mu_neg = jnp.mean(val_pos), jnp.mean(val_neg)
+            pos_wins = mu_pos > mu_neg
+            idx = jnp.where(pos_wins, idx_pos, idx_neg).astype(jnp.int32)
+        with scope("quantize"):
+            mu = jnp.where(pos_wins, mu_pos, -mu_neg).astype(jnp.float32)
+            own_row = jnp.zeros((n_loc,), out_dtype).at[idx].set(mu.astype(out_dtype))
         return None, (idx, mu, own_row)
 
-    _, (idx, mu, own) = jax.lax.scan(one_layer, None, acc_flat)
+    with scope("select"):  # the scan over layers
+        _, (idx, mu, own) = jax.lax.scan(one_layer, None, acc_flat)
 
     if client_axes and n_clients > 1:
         # THE exchange: tiny (idx, μ) tensors cross the client axes.
         gidx, gmu = idx, mu
-        for ax in client_axes:
-            gidx = jax.lax.all_gather(gidx, ax)
-            gmu = jax.lax.all_gather(gmu, ax)
-        gidx = gidx.reshape(n_clients, L, k)
-        gmu = gmu.reshape(n_clients, L)
+        with scope("all_gather"):
+            for ax in client_axes:
+                gidx = jax.lax.all_gather(gidx, ax)
+                gmu = jax.lax.all_gather(gmu, ax)
+        with scope("decode"):
+            gidx = gidx.reshape(n_clients, L, k)
+            gmu = gmu.reshape(n_clients, L)
 
-        def dense_layer(_, args):
-            rows_i, mus_i = args  # (C, k), (C,)
-            row = jnp.zeros((n_loc,), jnp.float32)
+            def dense_layer(_, args):
+                rows_i, mus_i = args  # (C, k), (C,)
+                row = jnp.zeros((n_loc,), jnp.float32)
 
-            def add(acc, ci):
-                return acc.at[rows_i[ci]].add(mus_i[ci] / n_clients), None
+                def add(acc, ci):
+                    return acc.at[rows_i[ci]].add(mus_i[ci] / n_clients), None
 
-            row, _ = jax.lax.scan(add, row, jnp.arange(n_clients))
-            return None, row.astype(out_dtype)
+                row, _ = jax.lax.scan(add, row, jnp.arange(n_clients))
+                return None, row.astype(out_dtype)
 
-        _, dense = jax.lax.scan(
-            dense_layer, None, (gidx.transpose(1, 0, 2), gmu.transpose(1, 0))
-        )
+            _, dense = jax.lax.scan(
+                dense_layer, None, (gidx.transpose(1, 0, 2), gmu.transpose(1, 0))
+            )
     else:
         dense = own
     return dense, own
@@ -367,8 +381,9 @@ def _sbc_local(acc_flat: jax.Array, p: float, client_axes, n_clients: int,
 def _dense_local(acc_flat, client_axes, n_clients):
     """Dense baseline: pmean over clients == all-reduce of the full ΔW."""
     out = acc_flat
-    for ax in client_axes:
-        out = jax.lax.pmean(out, ax)
+    with scope("apply"):
+        for ax in client_axes:
+            out = jax.lax.pmean(out, ax)
     return out, acc_flat
 
 
@@ -482,13 +497,14 @@ class ShardedGspmdChannel:
             )(residual, *delta_leaves)
         else:
             # residual add (Alg. 1 l.10): acc = R + ΔW
-            acc = jax.tree.map(
-                lambda r, d: (r.astype(jnp.float32) + d.astype(jnp.float32)).astype(
-                    self.residual_dtype
-                ),
-                residual,
-                deltas,
-            )
+            with scope("quantize"):
+                acc = jax.tree.map(
+                    lambda r, d: (r.astype(jnp.float32) + d.astype(jnp.float32)).astype(
+                        self.residual_dtype
+                    ),
+                    residual,
+                    deltas,
+                )
             acc_leaves = jax.tree.leaves(acc)
             mean_leaves, res_leaves, own_leaves = shard_map(
                 lambda *leaves: self.exchange_per_leaf(leaves, need_own),
@@ -525,13 +541,16 @@ class ShardedGspmdChannel:
             else:  # skip: no traffic; the residual keeps the full update
                 dense = jnp.zeros_like(flat, dtype=leaf.dtype)
                 own = dense
-            new_res = (flat.astype(jnp.float32) - own.astype(jnp.float32)).astype(
-                self.residual_dtype
-            )
-            means.append(dense.reshape(body.shape).astype(leaf.dtype)[None])
-            residuals.append(new_res.reshape(body.shape).astype(leaf.dtype)[None])
-            owns.append(own.reshape(body.shape).astype(leaf.dtype)[None]
-                        if need_own else jnp.zeros((1,) * leaf.ndim, leaf.dtype))
+            with scope("quantize"):
+                new_res = (flat.astype(jnp.float32) - own.astype(jnp.float32)).astype(
+                    self.residual_dtype
+                )
+            with scope("apply"):
+                means.append(dense.reshape(body.shape).astype(leaf.dtype)[None])
+            with scope("quantize"):
+                residuals.append(new_res.reshape(body.shape).astype(leaf.dtype)[None])
+                owns.append(own.reshape(body.shape).astype(leaf.dtype)[None]
+                            if need_own else jnp.zeros((1,) * leaf.ndim, leaf.dtype))
         return tuple(means), tuple(residuals), tuple(owns)
 
     def exchange_flat(self, res: jax.Array, leaves: Sequence[jax.Array],
@@ -551,19 +570,21 @@ class ShardedGspmdChannel:
             fn = (space.exchange_local if self.flat_engine == "exact"
                   else space.exchange_local_hist)
             mean_f, own_f, new_res_f = fn(bodies, res[0, 0])
-        means = tuple(
-            m.astype(leaf.dtype)[None] for m, leaf in
-            zip(space.unflatten_local(mean_f), leaves)
-        )
-        if need_own:
-            owns = tuple(
-                o.astype(leaf.dtype)[None] for o, leaf in
-                zip(space.unflatten_local(own_f), leaves)
+        with scope("apply"):
+            means = tuple(
+                m.astype(leaf.dtype)[None] for m, leaf in
+                zip(space.unflatten_local(mean_f), leaves)
             )
-        else:
-            owns = tuple(
-                jnp.zeros((1,) * leaf.ndim, leaf.dtype) for leaf in leaves
-            )
+        with scope("quantize"):
+            if need_own:
+                owns = tuple(
+                    o.astype(leaf.dtype)[None] for o, leaf in
+                    zip(space.unflatten_local(own_f), leaves)
+                )
+            else:
+                owns = tuple(
+                    jnp.zeros((1,) * leaf.ndim, leaf.dtype) for leaf in leaves
+                )
         if self.device_pack:
             return means, new_res_f[None, None], owns, packed
         return means, new_res_f[None, None], owns

@@ -31,6 +31,7 @@ from repro.core.stages import (
     get_selector,
     k_for,
 )
+from repro.obs.scopes import scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,7 +89,8 @@ class Codec:
             s_rng, q_rng = jax.random.split(rng)
         else:
             s_rng = q_rng = None
-        sel = self.selector(flat, p, s_rng)
+        with scope("select"):
+            sel = self.selector(flat, p, s_rng)
         vals_q, scalar = self.quantizer(sel, q_rng)
         if self.selector.skip:
             return LeafCompressed(

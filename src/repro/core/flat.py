@@ -53,6 +53,7 @@ import numpy as np
 
 from repro.core.golomb import golomb_bstar
 from repro.core.stages import LeafCompressed, k_for
+from repro.obs.scopes import scope
 from repro.kernels.ops import seg_sbc_hist
 from repro.kernels.pack import (
     bits_from_positions,
@@ -271,10 +272,11 @@ class FlatParamSpace:
                     nbits=jnp.asarray(codec.quantizer.value_bits(k), jnp.float32),
                 )
                 continue
-            val_pos, idx_pos = jax.lax.top_k(acc, k)
-            val_neg, idx_neg = jax.lax.top_k(-acc, k)
-            pos_wins = jnp.mean(val_pos) > jnp.mean(val_neg)
-            idx = jnp.where(pos_wins, idx_pos, idx_neg).astype(jnp.int32)
+            with scope("select"):
+                val_pos, idx_pos = jax.lax.top_k(acc, k)
+                val_neg, idx_neg = jax.lax.top_k(-acc, k)
+                pos_wins = jnp.mean(val_pos) > jnp.mean(val_neg)
+                idx = jnp.where(pos_wins, idx_pos, idx_neg).astype(jnp.int32)
             # μ re-gathers the winning side's ORIGINAL values, exactly like
             # the topk_signed selector + binarize quantizer composition —
             # down to the sign of −0.0 on an all-zero leaf
@@ -596,9 +598,10 @@ class ShardedFlatParamSpace:
         per-row bit counts (the per-client wire metering) and per-row μ.  The aggregated update,
         residual, and ΔW* are bit-identical to ``device_pack=False``.
         """
-        acc = self.flatten_local(bodies)
-        if res_flat is not None:
-            acc = res_flat + acc
+        with scope("quantize"):
+            acc = self.flatten_local(bodies)
+            if res_flat is not None:
+                acc = res_flat + acc
 
         pos_parts, mu_parts, idx_parts = [], [], []
         for s in self._sparse:
@@ -616,26 +619,29 @@ class ShardedFlatParamSpace:
                 mu = jnp.where(pos_wins, mu_pos, -mu_neg).astype(jnp.float32)
                 return None, (idx, mu)
 
-            _, (idx, mu) = jax.lax.scan(one_layer, None, x)
-            base = s.offset + np.arange(s.rows, dtype=np.int32) * s.n_loc
-            pos_parts.append((idx + jnp.asarray(base)[:, None]).reshape(-1))
+            with scope("select"):
+                _, (idx, mu) = jax.lax.scan(one_layer, None, x)
+                base = s.offset + np.arange(s.rows, dtype=np.int32) * s.n_loc
+                pos_parts.append((idx + jnp.asarray(base)[:, None]).reshape(-1))
             mu_parts.append(mu)
             idx_parts.append(idx)
 
-        own = jnp.zeros((self.n_pad,), jnp.float32)
-        if pos_parts:
-            pos = jnp.concatenate(pos_parts)
-            mu = jnp.concatenate(mu_parts)
-            pos_row = jnp.asarray(self._pos_row)
-            own = own.at[pos].set(jnp.take(mu, pos_row))
-        if self._dense_idx.size:
-            dense_idx = jnp.asarray(self._dense_idx)
-            dvals = acc[dense_idx]
-            own = own.at[dense_idx].set(dvals)
+        with scope("quantize"):
+            own = jnp.zeros((self.n_pad,), jnp.float32)
+            if pos_parts:
+                pos = jnp.concatenate(pos_parts)
+                mu = jnp.concatenate(mu_parts)
+                pos_row = jnp.asarray(self._pos_row)
+                own = own.at[pos].set(jnp.take(mu, pos_row))
+            if self._dense_idx.size:
+                dense_idx = jnp.asarray(self._dense_idx)
+                dvals = acc[dense_idx]
+                own = own.at[dense_idx].set(dvals)
 
         words = nbits = None
         if device_pack:
-            words, nbits = self._pack_local(idx_parts)
+            with scope("pack"):
+                words, nbits = self._pack_local(idx_parts)
 
         if self.client_axes and self.n_clients > 1 and pos_parts:
             # THE exchange: the packed (positions, μ) streams cross the
@@ -645,34 +651,38 @@ class ShardedFlatParamSpace:
             # index arrays.
             gsrc = words if device_pack else pos
             gmu = mu
-            for ax in self.client_axes:
-                gsrc = jax.lax.all_gather(gsrc, ax)
-                gmu = jax.lax.all_gather(gmu, ax)
-            gmu = gmu.reshape(self.n_clients, self.n_mu)
-            if device_pack:
-                gpos = self._decode_gathered(
-                    gsrc.reshape(self.n_clients, self.n_pack_words)
+            with scope("all_gather"):
+                for ax in self.client_axes:
+                    gsrc = jax.lax.all_gather(gsrc, ax)
+                    gmu = jax.lax.all_gather(gmu, ax)
+            with scope("decode"):
+                gmu = gmu.reshape(self.n_clients, self.n_mu)
+                if device_pack:
+                    gpos = self._decode_gathered(
+                        gsrc.reshape(self.n_clients, self.n_pack_words)
+                    )
+                else:
+                    gpos = gsrc.reshape(self.n_clients, self.n_pos)
+
+                def add_client(buf, ci):
+                    vals = jnp.take(gmu[ci], pos_row) / self.n_clients
+                    return buf.at[gpos[ci]].add(vals), None
+
+                mean, _ = jax.lax.scan(
+                    add_client, jnp.zeros((self.n_pad,), jnp.float32),
+                    jnp.arange(self.n_clients),
                 )
-            else:
-                gpos = gsrc.reshape(self.n_clients, self.n_pos)
-
-            def add_client(buf, ci):
-                vals = jnp.take(gmu[ci], pos_row) / self.n_clients
-                return buf.at[gpos[ci]].add(vals), None
-
-            mean, _ = jax.lax.scan(
-                add_client, jnp.zeros((self.n_pad,), jnp.float32),
-                jnp.arange(self.n_clients),
-            )
         else:
             mean = own
         if self._dense_idx.size and self.client_axes:
-            dv = dvals
-            for ax in self.client_axes:
-                dv = jax.lax.pmean(dv, ax)
-            mean = mean.at[dense_idx].set(dv)
+            with scope("apply"):
+                dv = dvals
+                for ax in self.client_axes:
+                    dv = jax.lax.pmean(dv, ax)
+                mean = mean.at[dense_idx].set(dv)
 
-        new_res = acc - own if res_flat is not None else None
+        with scope("quantize"):
+            new_res = acc - own if res_flat is not None else None
         if device_pack:
             return mean, own, new_res, words, nbits, mu
         return mean, own, new_res
@@ -743,20 +753,25 @@ class ShardedFlatParamSpace:
                 "exchange_local_hist needs an all-SBC policy; dense/skip "
                 "leaves belong to the exact engine"
             )
-        acc = self.flatten_local(bodies)
-        if res_flat is not None:
-            acc = res_flat + acc
-        own, res, _stats = seg_sbc_hist(
-            acc,
-            bounds=[(s.offset, s.rows * s.n_loc) for s in self.segments],
-            ks=[k_for(s.rows * s.n_loc, s.rate) for s in self.segments],
-            rates=[s.rate for s in self.segments],
-            bm=self.bm,
-            lanes=self.lanes,
-            nbins=nbins,
-        )
+        with scope("quantize"):
+            acc = self.flatten_local(bodies)
+            if res_flat is not None:
+                acc = res_flat + acc
+        # the kernel passes select and binarize in one sequence; its time
+        # reads as select
+        with scope("select"):
+            own, res, _stats = seg_sbc_hist(
+                acc,
+                bounds=[(s.offset, s.rows * s.n_loc) for s in self.segments],
+                ks=[k_for(s.rows * s.n_loc, s.rate) for s in self.segments],
+                rates=[s.rate for s in self.segments],
+                bm=self.bm,
+                lanes=self.lanes,
+                nbins=nbins,
+            )
         mean = own
-        for ax in self.client_axes:
-            mean = jax.lax.pmean(mean, ax)
+        with scope("apply"):
+            for ax in self.client_axes:
+                mean = jax.lax.pmean(mean, ax)
         new_res = res if res_flat is not None else None
         return mean, own, new_res

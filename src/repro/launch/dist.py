@@ -68,6 +68,7 @@ from repro.core.codec import Codec, make_codec
 from repro.core.flat import ShardedFlatParamSpace
 from repro.core.policy import CompressionPolicy, path_str
 from repro.models import hints
+from repro.obs.scopes import scope
 from repro.models.model import Model, build_model
 from repro.optim.optimizers import get_optimizer
 
@@ -446,15 +447,18 @@ def build_dist_train(
         params = state["params"]
 
         def local(opt_state, client_batch):
-            loss, g = jax.value_and_grad(model.loss_fn)(params, client_batch)
-            p2, os2 = opt.apply(opt_state, g, params, cfg.base_lr, jnp.zeros((), jnp.int32))
-            delta = jax.tree.map(
-                lambda a, b: (a.astype(jnp.float32) - b.astype(jnp.float32)).astype(
-                    cfg.residual_dtype
-                ),
-                p2,
-                params,
-            )
+            with scope("forward_backward"):
+                loss, g = jax.value_and_grad(model.loss_fn)(params, client_batch)
+            with scope("optimizer"):
+                p2, os2 = opt.apply(opt_state, g, params, cfg.base_lr,
+                                    jnp.zeros((), jnp.int32))
+                delta = jax.tree.map(
+                    lambda a, b: (a.astype(jnp.float32) - b.astype(jnp.float32)).astype(
+                        cfg.residual_dtype
+                    ),
+                    p2,
+                    params,
+                )
             return delta, os2, loss
 
         deltas, opt_states, losses = jax.vmap(local)(state["opt"], batch)
@@ -474,23 +478,24 @@ def build_dist_train(
                 need_own=need_own,
             )
 
-        # every client reconstructs the identical mean update; take client 0
-        mean_delta = jax.tree.map(lambda m: m[0], mean_tree)
+        with scope("apply"):
+            # every client reconstructs the identical mean update; take client 0
+            mean_delta = jax.tree.map(lambda m: m[0], mean_tree)
 
-        new_params = jax.tree.map(
-            lambda p, d: (p.astype(jnp.float32) + d.astype(jnp.float32)).astype(p.dtype),
-            params,
-            mean_delta,
-        )
-        # momentum masking (supplement A) at transmitted coordinates
-        if need_mask:
-            transmitted = jax.tree.map(lambda o: (o != 0).astype(jnp.float32), own_tree)
-            opt_states = jax.vmap(opt.mask)(opt_states, transmitted)
+            new_params = jax.tree.map(
+                lambda p, d: (p.astype(jnp.float32) + d.astype(jnp.float32)).astype(p.dtype),
+                params,
+                mean_delta,
+            )
+            # momentum masking (supplement A) at transmitted coordinates
+            if need_mask:
+                transmitted = jax.tree.map(lambda o: (o != 0).astype(jnp.float32), own_tree)
+                opt_states = jax.vmap(opt.mask)(opt_states, transmitted)
 
-        metrics = {"loss": jnp.mean(losses)}
-        if measure:
-            # client 0's transmitted ΔW*, for host-side wire metering
-            metrics["own_client0"] = jax.tree.map(lambda o: o[0], own_tree)
+            metrics = {"loss": jnp.mean(losses)}
+            if measure:
+                # client 0's transmitted ΔW*, for host-side wire metering
+                metrics["own_client0"] = jax.tree.map(lambda o: o[0], own_tree)
             if device_pack:
                 # every client's upload: packed words, exact per-(client,
                 # shard, row) bits, per-row μ (the host can decode it)

@@ -8,9 +8,12 @@ and the serve-side planner read it off the objects they already hold,
 and the exporters in :mod:`repro.obs.export` turn it into a metrics
 JSONL + a Perfetto ``trace.json`` at the end of the run.
 
-Disabled telemetry is the shared :data:`NULL_TELEMETRY` singleton — all
-no-ops, identity ``fence`` (no added device synchronization), gated
-below 1% step-time overhead by ``benchmarks/run_api_overhead.py``.
+Disabled telemetry is the shared :data:`NULL_TELEMETRY` singleton — no
+recorded events, identity ``fence`` (no added device synchronization),
+gated below 1% step-time overhead by ``benchmarks/run_api_overhead.py``.
+Its spans still reach a profiler trace (:class:`~repro.obs.trace.ProfiledSpan`),
+and the jitted round's device stages are named scopes
+(:mod:`repro.obs.scopes`) joined to the trace through the compiled HLO.
 """
 from __future__ import annotations
 
@@ -31,11 +34,14 @@ from repro.obs.metrics import (
     NullMetrics,
     validate_metric_events,
 )
+from repro.obs.scopes import SCOPE_NAMES, hlo_stage_map, scope, stage_of
 from repro.obs.trace import (
     NULL_TRACER,
     NullTracer,
+    ProfiledSpan,
     SPAN_NAMES,
     Tracer,
+    profiled_spans,
     validate_span_events,
 )
 
@@ -52,7 +58,9 @@ class Telemetry:
         return self.tracer.enabled or self.metrics.enabled
 
     def span(self, name: str, **args):
-        return self.tracer.span(name, **args)
+        """A host span, in the run's tracer when telemetry is on and in a
+        profiler trace whenever one is recording."""
+        return ProfiledSpan(self.tracer, name, args)
 
     def fence(self, x):
         return self.tracer.fence(x)
@@ -99,13 +107,18 @@ __all__ = [
     "NullMetrics",
     "NullTracer",
     "SCHEMA",
+    "SCOPE_NAMES",
     "SPAN_NAMES",
     "Telemetry",
     "Tracer",
     "finish_run",
+    "hlo_stage_map",
     "make_telemetry",
+    "profiled_spans",
     "render_table",
+    "scope",
     "span_table",
+    "stage_of",
     "summary_table",
     "validate_metric_events",
     "validate_span_events",

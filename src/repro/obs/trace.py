@@ -1,10 +1,20 @@
-"""Round-structured tracing: nested spans with device-timing fences.
+"""Round-structured tracing: nested host spans, on the profiler's clock.
 
 The :class:`Tracer` records *complete spans* — named, nested intervals
-with microsecond wall-clock timestamps — plus point-in-time instants.
-One communication round produces one ``round`` span whose children are
-the stage spans of that backend (the taxonomy lives in ``SPAN_NAMES``
-and docs/observability.md).
+with microsecond wall-clock timestamps.  One communication round
+produces one ``round`` span whose children are the host stage spans of
+that backend (the taxonomy lives in ``SPAN_NAMES`` and
+docs/observability.md); the device stages inside a jitted round are
+named scopes instead (:mod:`repro.obs.scopes`).
+
+Every span a :class:`~repro.obs.Telemetry` handle opens is a
+:class:`ProfiledSpan`: it always enters a ``jax.profiler.TraceAnnotation``
+(tagged with its arguments, the round index among them), so a profiler
+trace holds the program's spans beside the device ops on one clock —
+whether the run's telemetry is on or off.  While a profiler session is
+active it also records the span in one bounded, process-wide
+:class:`Tracer`, read back with :func:`profiled_spans` by the process
+that holds the trace.
 
 Device timing is only meaningful if the traced interval actually waits
 for the device: jax dispatch returns before the computation finishes, so
@@ -13,21 +23,22 @@ on the outputs before exiting (``jax.block_until_ready``).  The fence is
 a no-op on the disabled tracer — tracing off means *no* added
 synchronization, not just no recorded events.
 
-Zero-overhead-by-default: :data:`NULL_TRACER` is a singleton whose
-``span()`` returns one shared no-op context manager and whose ``fence``
-is identity.  Instrumented call sites hold a tracer unconditionally
-(never ``if tracer:`` branches around jax calls), so the disabled cost
-is one attribute lookup and an empty ``with`` per stage per round —
-gated below 1% of step time by ``benchmarks/run_api_overhead.py``.
-
-No dependencies beyond the standard library (jax is imported lazily and
-only by an *enabled* fence).
+Cheap by default: :data:`NULL_TRACER` is a singleton whose ``span()``
+returns one shared no-op context manager and whose ``fence`` is
+identity.  Instrumented call sites hold a tracer unconditionally (never
+``if tracer:`` branches around jax calls), so with telemetry off a span
+costs one ``TraceAnnotation`` with no session to record it (about a
+microsecond) — gated below 1% of step time by
+``benchmarks/run_api_overhead.py``.
 """
 from __future__ import annotations
 
+import collections
 import json
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 # The span taxonomy — every span a repro component emits is named here
 # (docs/observability.md documents each; tests/test_docs_consistency.py
@@ -35,8 +46,11 @@ from typing import Any, Dict, List
 SPAN_NAMES: Dict[str, str] = {
     "round": "one communication round (parent of all stage spans)",
     "select_quantize": "client-side selection + quantization compute",
+    "dispatch": "local/gspmd Run.step: rate resolve, batch, and the jitted "
+                "round's call up to its return",
+    "fetch": "the copy of client 0's compressed upload to the host "
+             "(args: arrays, bytes)",
     "encode": "host-side wire encoding (SBW1 pack / Golomb streams)",
-    "exchange": "the exchange itself (jitted collective or wire transfer)",
     "decode": "server-side unpack of client uploads",
     "apply": "aggregate + apply the round update to the master weights",
     "plan": "serve-side catch-up planning for one lag class",
@@ -99,12 +113,14 @@ _NULL_SPAN = _NullSpan()
 
 
 class Tracer:
-    """Collects nested spans + instants as JSONL-able event dicts."""
+    """Collects nested spans as JSONL-able event dicts; with
+    ``max_events`` only the newest that many are kept."""
 
     enabled = True
 
-    def __init__(self) -> None:
-        self.events: List[dict] = []
+    def __init__(self, max_events: Optional[int] = None) -> None:
+        self.events = (collections.deque(maxlen=max_events)
+                       if max_events else [])
         self._stack: List[_Span] = []
         self._next_id = 0
         self._epoch_ns = time.perf_counter_ns()
@@ -117,14 +133,6 @@ class Tracer:
     def span(self, name: str, **args: Any) -> _Span:
         """Open a nested span: ``with tracer.span("encode", leaf=path): ...``"""
         return _Span(self, name, args)
-
-    def instant(self, name: str, **args: Any) -> None:
-        self.events.append({
-            "type": "instant",
-            "name": name,
-            "ts_us": self._now_us(),
-            "args": args,
-        })
 
     def fence(self, x: Any) -> Any:
         """Block until ``x``'s device computation finished, so the
@@ -139,21 +147,11 @@ class Tracer:
 
     def chrome_events(self) -> List[dict]:
         """Chrome/Perfetto ``traceEvents`` (complete-span ``ph: "X"``)."""
-        out = []
-        for e in self.events:
-            if e["type"] == "span":
-                out.append({
-                    "ph": "X", "name": e["name"], "cat": "repro",
-                    "ts": e["ts_us"], "dur": e["dur_us"],
-                    "pid": 0, "tid": 0, "args": e["args"],
-                })
-            elif e["type"] == "instant":
-                out.append({
-                    "ph": "i", "name": e["name"], "cat": "repro",
-                    "ts": e["ts_us"], "pid": 0, "tid": 0, "s": "t",
-                    "args": e["args"],
-                })
-        return out
+        return [{
+            "ph": "X", "name": e["name"], "cat": "repro",
+            "ts": e["ts_us"], "dur": e["dur_us"],
+            "pid": 0, "tid": 0, "args": e["args"],
+        } for e in self.events]
 
     def write_chrome(self, path: str) -> str:
         """Write a Perfetto-loadable ``trace.json`` (ui.perfetto.dev /
@@ -177,14 +175,60 @@ class NullTracer:
     def span(self, name: str, **args: Any) -> _NullSpan:
         return _NULL_SPAN
 
-    def instant(self, name: str, **args: Any) -> None:
-        return None
-
     def fence(self, x: Any) -> Any:
         return x
 
 
 NULL_TRACER = NullTracer()
+
+# the spans opened while a profiler session is active, for the process
+# that holds the trace (bounded: a long session keeps the newest)
+_PROFILED = Tracer(max_events=1 << 16)
+_profiling = False  # whether the previous span saw a session
+
+
+def profiled_spans() -> List[dict]:
+    """The span events recorded in the newest profiler session, oldest
+    first: each with its ``name``, ``args`` (the round among them),
+    ``id``, ``parent`` and ``dur_us``.  A session starts afresh at its
+    first span after a span opened with no session."""
+    return list(_PROFILED.events)
+
+
+def _mirror(name: str, args: dict):
+    global _profiling
+    on = TraceAnnotation.is_enabled()
+    if on and not _profiling:
+        _PROFILED.events.clear()
+    _profiling = on
+    return _PROFILED.span(name, **args) if on else None
+
+
+class ProfiledSpan:
+    """A span on the profiler's clock: a ``TraceAnnotation`` always, the
+    run tracer's span, and — while a profiler session is active — a record
+    in the process-wide profiled tracer.  ``with`` binds the run tracer's
+    span."""
+
+    __slots__ = ("_annotation", "_span", "_mirror")
+
+    def __init__(self, tracer, name: str, args: dict):
+        self._annotation = TraceAnnotation(name, **args)
+        self._span = tracer.span(name, **args)
+        self._mirror = _mirror(name, args)
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        if self._mirror is not None:
+            self._mirror.__enter__()
+        return self._span.__enter__()
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._span.__exit__(exc_type, exc, tb)
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
+        self._annotation.__exit__(exc_type, exc, tb)
+        return False
 
 
 def validate_span_events(events: List[dict]) -> List[str]:
@@ -195,21 +239,18 @@ def validate_span_events(events: List[dict]) -> List[str]:
     spans: Dict[int, dict] = {}
     for i, e in enumerate(events):
         t = e.get("type")
-        if t == "span":
-            for field in ("name", "id", "depth", "ts_us", "dur_us", "args"):
-                if field not in e:
-                    errs.append(f"event {i}: span missing {field!r}")
-            if e.get("dur_us", -1) < 0:
-                errs.append(f"span {e.get('name')}: negative duration")
-            if e.get("name") not in SPAN_NAMES:
-                errs.append(f"span name {e.get('name')!r} not in SPAN_NAMES")
-            if "id" in e:
-                spans[e["id"]] = e
-        elif t == "instant":
-            if "name" not in e or "ts_us" not in e:
-                errs.append(f"event {i}: malformed instant")
-        else:
+        if t != "span":
             errs.append(f"event {i}: unknown trace event type {t!r}")
+            continue
+        for field in ("name", "id", "depth", "ts_us", "dur_us", "args"):
+            if field not in e:
+                errs.append(f"event {i}: span missing {field!r}")
+        if e.get("dur_us", -1) < 0:
+            errs.append(f"span {e.get('name')}: negative duration")
+        if e.get("name") not in SPAN_NAMES:
+            errs.append(f"span name {e.get('name')!r} not in SPAN_NAMES")
+        if "id" in e:
+            spans[e["id"]] = e
     for e in spans.values():
         pid = e.get("parent")
         if pid is None:

@@ -70,17 +70,16 @@ def _check_trace(path: str) -> List[str]:
     open_stacks: Dict[tuple, list] = {}
     for i, e in enumerate(events):
         ph = e.get("ph")
-        if ph not in ("X", "i"):
+        if ph != "X":
             errs.append(f"{path}: event {i} has unsupported ph {ph!r}")
             continue
         if "name" not in e or "ts" not in e:
             errs.append(f"{path}: event {i} missing name/ts")
             continue
-        if ph == "X":
-            if e.get("dur", -1) < 0:
-                errs.append(f"{path}: span {e['name']} negative duration")
-            if e["name"] not in SPAN_NAMES:
-                errs.append(f"{path}: span name {e['name']!r} not in taxonomy")
+        if e.get("dur", -1) < 0:
+            errs.append(f"{path}: span {e['name']} negative duration")
+        if e["name"] not in SPAN_NAMES:
+            errs.append(f"{path}: span name {e['name']!r} not in taxonomy")
     # nesting: within one (pid, tid), sorted complete spans must not
     # partially overlap — each pair is either disjoint or contained
     spans = sorted(

@@ -118,6 +118,15 @@ class Run:
     def step(self, state, round_idx: int) -> tuple:
         raise NotImplementedError
 
+    def stage_map(self, state, round_idx: int = 0) -> dict:
+        """``{compiled instruction: stage}`` of the round :meth:`step` runs
+        from ``state`` (:func:`repro.obs.hlo_stage_map`): lowers and
+        compiles the jitted round with the step's own arguments.  The
+        instructions are those of the program ``step`` runs; a profiler
+        trace names each device op by its instruction, so this map reads
+        the trace by stage."""
+        raise NotImplementedError
+
     def evaluate(self, state) -> dict:
         """Held-out loss: a batch stream no training client consumes.
 
@@ -244,17 +253,21 @@ class LocalRun(Run):
             rng = jax.random.PRNGKey(self.spec.seed)
         return self.trainer.init(rng)
 
+    def _round_args(self, state, round_idx: int) -> tuple:
+        """The jitted round's arguments: (state, batch), static kwargs."""
+        rates = self.trainer.resolved(state.params).rates(
+            self.spec.sparsity, round_idx)
+        return (state, self.batch_fn(round_idx)), dict(
+            n_delay=self.spec.delay, sparsity=rates,
+            return_compressed=self.spec.measure_wire,
+        )
+
     def step(self, state, round_idx: int) -> tuple:
-        resolved = self.trainer.resolved(state.params)
-        rates = resolved.rates(self.spec.sparsity, round_idx)
-        # local select/quantize/exchange/apply fuse into ONE jitted round
-        # (docs/observability.md) — honestly traced as one fused exchange
-        with self.telemetry.span("exchange", round=round_idx, fused=True):
-            out = self.trainer.round_step(
-                state, self.batch_fn(round_idx), n_delay=self.spec.delay,
-                sparsity=rates, return_compressed=self.spec.measure_wire,
-            )
-            self.telemetry.fence(out[0].params)
+        # the whole round is one jitted call; its stages are named scopes
+        # inside it (stage_map), so the host sees only the dispatch
+        with self.telemetry.span("dispatch", round=round_idx):
+            args, static = self._round_args(state, round_idx)
+            out = self.trainer.round_step(*args, **static)
         if self.spec.measure_wire:
             state, m, comp0 = out
             m = dict(m)
@@ -266,6 +279,14 @@ class LocalRun(Run):
         else:
             state, m = out
         return state, {k: v for k, v in m.items()}
+
+    def stage_map(self, state, round_idx: int = 0) -> dict:
+        from repro.obs.scopes import compiled_stage_map
+
+        args, static = self._round_args(state, round_idx)
+        # round_step is jitted on the class: the trainer is its static self
+        return compiled_stage_map(
+            type(self.trainer).round_step.lower(self.trainer, *args, **static))
 
     def checkpoint(self, state, path: str) -> None:
         from repro.checkpoint.io import save_train_state
@@ -372,11 +393,10 @@ class GspmdRun(Run):
         return jax.tree.map(lambda *xs: jnp.stack(xs), *per)
 
     def step(self, state, round_idx: int) -> tuple:
-        # the shard_map round (compress + collective + apply) is one jitted
-        # fused call — traced as one exchange span (docs/observability.md)
-        with self.telemetry.span("exchange", round=round_idx, fused=True):
+        # the shard_map round is one jitted call; its stages are named
+        # scopes inside it (stage_map)
+        with self.telemetry.span("dispatch", round=round_idx):
             state, m = self.fns.train_step(state, self._batch(round_idx))
-            self.telemetry.fence(state["params"])
         m = dict(m)
         if self.spec.measure_wire:
             own_client0 = m.pop("own_client0")
@@ -389,6 +409,12 @@ class GspmdRun(Run):
         m["bits_per_client"] = self.fns.bits_per_client
         m["bits_dense"] = self.fns.bits_dense
         return state, m
+
+    def stage_map(self, state, round_idx: int = 0) -> dict:
+        from repro.obs.scopes import compiled_stage_map
+
+        return compiled_stage_map(
+            self.fns.train_step.lower(state, self._batch(round_idx)))
 
     def checkpoint(self, state, path: str) -> None:
         from repro.checkpoint.io import save_pytree

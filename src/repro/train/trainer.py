@@ -48,6 +48,7 @@ from repro.core.api import Compressor, CompressorState
 from repro.core.channel import LocalVmapChannel
 from repro.core.policy import CompressionPolicy, ResolvedPolicy
 from repro.models.model import Model
+from repro.obs.scopes import scope
 from repro.optim.optimizers import Optimizer
 
 PyTree = Any
@@ -139,28 +140,35 @@ class DSGDTrainer:
         return_compressed: bool = False,
     ) -> tuple:
         params = state.params
-        iteration = state.round * n_delay  # forward-backward passes so far
+        with scope("forward_backward"):
+            iteration = state.round * n_delay  # forward-backward passes so far
 
         def local_update(opt_state, client_batch):
             """n_delay local steps from the master weights (Alg. 1 l.10)."""
 
             def one(carry, micro):
                 p, os, it = carry
-                loss, g = jax.value_and_grad(self.model.loss_fn)(p, micro)
-                p2, os2 = self.optimizer.apply(os, g, p, self.lr(it), it)
+                with scope("forward_backward"):
+                    loss, g = jax.value_and_grad(self.model.loss_fn)(p, micro)
+                with scope("optimizer"):
+                    p2, os2 = self.optimizer.apply(os, g, p, self.lr(it), it)
                 return (p2, os2, it + 1), loss
 
-            (p_new, os_new, _), losses = jax.lax.scan(
-                one, (params, opt_state, iteration), client_batch
-            )
-            delta = jax.tree.map(
-                lambda a, b: (a.astype(jnp.float32) - b.astype(jnp.float32)).astype(
-                    self.residual_dtype
-                ),
-                p_new,
-                params,
-            )
-            return delta, os_new, jnp.mean(losses)
+            with scope("forward_backward"):
+                (p_new, os_new, _), losses = jax.lax.scan(
+                    one, (params, opt_state, iteration), client_batch
+                )
+            with scope("optimizer"):
+                delta = jax.tree.map(
+                    lambda a, b: (a.astype(jnp.float32) - b.astype(jnp.float32)).astype(
+                        self.residual_dtype
+                    ),
+                    p_new,
+                    params,
+                )
+            with scope("forward_backward"):
+                loss = jnp.mean(losses)
+            return delta, os_new, loss
 
         deltas, opt_states, losses = jax.vmap(local_update)(state.opt_states, batch)
 
@@ -171,26 +179,27 @@ class DSGDTrainer:
             deltas, state.comp_state, sparsity,
             return_compressed=return_compressed,
         )
-        new_params = jax.tree.map(
-            lambda p, d: (p.astype(jnp.float32) + d.astype(jnp.float32)).astype(p.dtype),
-            params,
-            ex.mean_delta,
-        )
+        with scope("apply"):
+            new_params = jax.tree.map(
+                lambda p, d: (p.astype(jnp.float32) + d.astype(jnp.float32)).astype(p.dtype),
+                params,
+                ex.mean_delta,
+            )
 
-        # ---- momentum masking at transmitted coordinates (supplement A)
-        transmitted = jax.tree.map(
-            lambda d: (d != 0).astype(jnp.float32), ex.transmitted
-        )
-        opt_states = jax.vmap(self.optimizer.mask)(opt_states, transmitted)
+            # ---- momentum masking at transmitted coordinates (supplement A)
+            transmitted = jax.tree.map(
+                lambda d: (d != 0).astype(jnp.float32), ex.transmitted
+            )
+            opt_states = jax.vmap(self.optimizer.mask)(opt_states, transmitted)
 
-        n_params = sum(x.size for x in jax.tree.leaves(params))
-        metrics = {
-            "loss": jnp.mean(losses),
-            "bits_per_client": ex.bits_per_client,
-            "bits_dense": jnp.asarray(32.0 * n_params * n_delay, jnp.float32),
-            "update_norm": _tree_norm(ex.mean_delta),
-        }
-        new_state = TrainState(new_params, opt_states, ex.state, state.round + 1)
+            n_params = sum(x.size for x in jax.tree.leaves(params))
+            metrics = {
+                "loss": jnp.mean(losses),
+                "bits_per_client": ex.bits_per_client,
+                "bits_dense": jnp.asarray(32.0 * n_params * n_delay, jnp.float32),
+                "update_norm": _tree_norm(ex.mean_delta),
+            }
+            new_state = TrainState(new_params, opt_states, ex.state, state.round + 1)
         if return_compressed:
             # client 0's compressed tree, for host-side wire measurement
             return new_state, metrics, ex.compressed0

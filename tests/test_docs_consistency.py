@@ -150,6 +150,18 @@ def test_observability_doc_covers_span_and_metric_registries():
     assert not unknown, f"docs/observability.md names unknown spans: {unknown}"
 
 
+def test_observability_doc_covers_stage_scopes():
+    """The stage-scope table of docs/observability.md names every scope in
+    SCOPE_NAMES, and no other."""
+    from repro.obs import SCOPE_NAMES
+
+    doc = (REPO / "docs" / "observability.md").read_text()
+    documented = set(re.findall(r"^\| `sbc\.([a-z_]+)` \|", doc, re.M))
+    assert documented == set(SCOPE_NAMES), (
+        f"undocumented: {set(SCOPE_NAMES) - documented}, "
+        f"unknown: {documented - set(SCOPE_NAMES)}")
+
+
 def test_design_section_10_documents_flat_path():
     """DESIGN.md must carry the §10 FlatParamSpace layout contract the
     fast-path code points at."""

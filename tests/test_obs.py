@@ -11,7 +11,10 @@ Covers, in order:
   * the JSONL / Chrome-trace export schema round trip + repro.obs.view;
   * NULL_TELEMETRY zero-overhead semantics (no-ops, identity fence);
   * an end-to-end traced tiny run: round → stage span decomposition and
-    gauges reconciled against the run's own ledger.
+    gauges reconciled against the run's own ledger;
+  * the device stages of the jitted round: ``stage_of`` on the op_name
+    forms the compiler writes, the compiled-HLO join, the LeNet rounds'
+    stage maps; and host spans reaching a profiler trace.
 """
 from __future__ import annotations
 
@@ -24,10 +27,15 @@ from repro.obs import (
     METRIC_NAMES,
     MetricsRegistry,
     NULL_TELEMETRY,
+    SCOPE_NAMES,
     Telemetry,
     Tracer,
+    hlo_stage_map,
     make_telemetry,
+    profiled_spans,
     render_table,
+    scope,
+    stage_of,
     validate_metric_events,
     validate_span_events,
     write_metrics_jsonl,
@@ -294,8 +302,17 @@ class TestTracedRun:
                  if e["type"] == "span"]
         rounds = [e for e in spans if e["name"] == "round"]
         assert len(rounds) == 2
-        kids = {e["name"] for e in spans if e["parent"] is not None}
-        assert "exchange" in kids and "encode" in kids
+        kids = [e for e in spans if e["parent"] is not None]
+        # the jitted round is one host dispatch; the metering that follows
+        # fetches client 0's upload, then encodes it
+        for r, rnd in enumerate(rounds):
+            mine = [e["name"] for e in kids if e["parent"] == rnd["id"]]
+            assert mine == ["dispatch", "fetch", "encode"], mine
+            for e in kids:
+                if e["parent"] == rnd["id"]:
+                    assert e["args"]["round"] == r
+        fetch = next(e for e in kids if e["name"] == "fetch")
+        assert fetch["args"]["arrays"] > 0 and fetch["args"]["bytes"] > 0
 
     def test_gauges_reconcile_with_run_ledger(self, traced):
         run, _ = traced
@@ -320,3 +337,209 @@ class TestTracedRun:
         write_trace_json(t, run.telemetry.tracer)
         write_metrics_jsonl(m, run.telemetry.metrics)
         assert view_check([t, m]) == 0
+
+
+# ------------------------------------------------- device stages and spans
+
+
+class TestStageOf:
+    @pytest.mark.parametrize("op_name, stage", [
+        ("jit(_round_step)/vmap(sbc.quantize)/sub", "quantize"),
+        ("jit(f)/vmap(sbc.forward_backward)/while/body/closed_call/"
+         "sbc.forward_backward/transpose(jvp())/conv_general_dilated",
+         "forward_backward"),
+        ("jit(f)/sbc.forward_backward/while/body/sbc.optimizer/mul",
+         "optimizer"),
+        ("jit(f)/transpose(jvp(sbc.forward_backward))/dot_general",
+         "forward_backward"),
+        ("jit(f)/vmap(sbc.quantize)/vmap(sbc.select)/top_k", "select"),
+        ("jit(f)/vmap()/broadcast_in_dim;jit(f)/sbc.apply/add", "apply"),
+        ("jit(f)/sbc.apply/mul;jit(f)/sbc.quantize/sub", "apply"),
+        ("jit(f)/vmap()/select_n", None),
+        ("jit(f)/transpose(jvp())/select_and_scatter", None),
+        ("jit(f)/xsbc.select/mul", None),
+        ("jit(f)/sbc.unknown/mul", None),
+    ])
+    def test_innermost_declared_scope(self, op_name, stage):
+        assert stage_of(op_name) == stage
+
+    def test_scope_refuses_undeclared_names(self):
+        with pytest.raises(KeyError, match="SCOPE_NAMES"):
+            scope("exchange")
+
+
+HLO = """HloModule m, is_scheduled=true
+
+%fused (p.0: f32[4]) -> f32[4] {
+  %p.0 = f32[4]{0} parameter(0)
+  ROOT %inner = f32[4]{0} multiply(%p.0, %p.0), metadata={op_name="jit(f)/sbc.apply/mul"}
+}
+
+%body (w.0: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %w.0 = (s32[], f32[4]{0}) parameter(0)
+  %i.0 = s32[] get-tuple-element(%w.0), index=0
+  %x.0 = f32[4]{0} get-tuple-element(%w.0), index=1
+  %one = s32[] constant(1)
+  %i.1 = s32[] add(%i.0, %one)
+  %x.1 = f32[4]{0} copy(%x.0)
+  ROOT %t.0 = (s32[], f32[4]{0}) tuple(%i.1, %x.1)
+}
+
+%cond (w.1: (s32[], f32[4])) -> pred[] {
+  %w.1 = (s32[], f32[4]{0}) parameter(0)
+  %i.2 = s32[] get-tuple-element(%w.1), index=0
+  %n = s32[] constant(4)
+  ROOT %lt = pred[] compare(%i.2, %n), direction=LT
+}
+
+ENTRY %main (a: f32[4]) -> f32[4] {
+  %a = f32[4]{0} parameter(0)
+  %g = f32[4]{0} sine(%a), metadata={op_name="jit(f)/vmap(sbc.forward_backward)/while/body/sin;jit(f)/sbc.optimizer/sin"}
+  %c = f32[4]{1,0} copy(%g)
+  %zero = s32[] constant(0)
+  %t = (s32[], f32[4]{0}) tuple(%zero, %c)
+  %loop = (s32[], f32[4]{0}) while(%t), condition=%cond, body=%body
+  %out = f32[4]{0} get-tuple-element(%loop), index=1
+  %pre = f32[4]{0} copy(%a)
+  %fu = f32[4]{0} fusion(%pre), kind=kLoop, calls=%fused, metadata={op_name="jit(f)/sbc.apply/mul"}
+  ROOT %r = f32[4]{0} add(%out, %fu), metadata={op_name="jit(f)/sbc.quantize/add"}
+}
+"""
+
+
+class TestHloStageMap:
+    def test_joins_compiled_instructions_to_stages(self):
+        m = hlo_stage_map(HLO)
+        assert m["g"] == "forward_backward"  # first ';'-joined name wins
+        assert m["c"] == "forward_backward"  # a layout copy: its producer's
+        assert m["pre"] == "apply"  # no producer with a stage: its user's
+        # a compiler-made loop with no metadata: the stage of what runs it
+        assert m["loop"] == "forward_backward"
+        assert m["i.1"] == m["x.1"] == m["lt"] == "forward_backward"
+        assert m["fu"] == "apply" and m["r"] == "quantize"
+        # fused bodies are not device ops; parameters, constants, tuples,
+        # tuple reads and bitcasts are not compute
+        assert "inner" not in m and "a" not in m and "zero" not in m
+        assert "t" not in m and "out" not in m
+
+    def test_no_entry_is_an_error(self):
+        with pytest.raises(ValueError, match="ENTRY"):
+            hlo_stage_map("HloModule m\n")
+
+
+def _lenet_run(compressor, fast=False):
+    from repro.run import RunSpec, build_run
+
+    sbc = compressor == "sbc"
+    return build_run(RunSpec(
+        preset="lenet5", backend="local", compressor=compressor,
+        sparsity=0.01, delay=2 if sbc else 1, clients=2, batch=4,
+        measure_wire=sbc, fast=fast,
+    ))
+
+
+class TestRoundStageMap:
+    @pytest.mark.parametrize("compressor, fast, stages", [
+        ("sbc", False, {"forward_backward", "optimizer", "select",
+                        "quantize", "apply"}),
+        ("sbc", True, {"forward_backward", "optimizer", "select",
+                       "quantize", "apply"}),  # the flat-buffer engine
+        # the lossless codec still carries error feedback (quantize) but
+        # selects nothing
+        ("none", False, {"forward_backward", "optimizer", "quantize",
+                         "apply"}),
+    ])
+    def test_every_stage_is_named_and_little_is_unscoped(self, compressor,
+                                                         fast, stages):
+        run = _lenet_run(compressor, fast)
+        m = run.stage_map(run.init(), 0)
+        assert set(m.values()) - {None} == stages
+        assert set(m.values()) <= set(SCOPE_NAMES) | {None}
+        unscoped = [k for k, v in m.items() if v is None]
+        assert len(unscoped) <= 0.02 * len(m), unscoped
+
+    def test_map_names_the_program_step_runs(self):
+        import jax
+
+        run = _lenet_run("none")
+        state = run.init()
+        m = run.stage_map(state, 0)
+        args, static = run._round_args(state, 0)
+        text = type(run.trainer).round_step.lower(
+            run.trainer, *args, **static).compile().as_text()
+        for name in m:
+            assert f"%{name} = " in text
+        jax.block_until_ready(run.step(state, 0))
+
+
+class TestProfiledSpans:
+    def test_spans_reach_the_profiler_with_telemetry_off(self, tmp_path):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        from repro.run import RunSpec, build_run
+
+        run = build_run(RunSpec(preset="tiny", backend="local", rounds=2,
+                                batch=4, seq_len=16, clients=2,
+                                sparsity=0.05, measure_wire=True))
+        assert not run.telemetry.enabled
+        state = run.init()
+        state, _ = run.step(state, 0)  # compiled, and seen with no session
+        jax.block_until_ready(state)
+        with jax.profiler.trace(str(tmp_path)):
+            for r in (1, 2):
+                state, _ = run.step(state, r)
+                jax.block_until_ready(state)
+        spans = profiled_spans()
+        assert validate_span_events(spans) == []
+        by_name = {}
+        for e in spans:
+            by_name.setdefault(e["name"], []).append(e["args"]["round"])
+        assert by_name == {"dispatch": [1, 2], "fetch": [1, 2],
+                           "encode": [1, 2]}
+        assert all(e["parent"] is None for e in spans)
+
+        xplane = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                           recursive=True)
+        host = [(ev.name, dict(ev.stats))
+                for plane in ProfileData.from_file(xplane[0]).planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines for ev in line.events
+                if ev.name in ("dispatch", "fetch", "encode")]
+        assert sorted((n, s["round"]) for n, s in host) == sorted(
+            (n, r) for n, rs in by_name.items() for r in rs)
+
+        state, _ = run.step(state, 3)  # no session: nothing recorded
+        jax.block_until_ready(state)
+        assert profiled_spans() == spans
+
+
+def test_gspmd_round_stage_map_on_4_devices():
+    """The gspmd round over 4 forced host devices, on each exchange path:
+    its stage map names the local steps, selection, the gather of the
+    packed uploads, their decode and the apply, and leaves nothing
+    unscoped — the decomposition a four-chip cell reads."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "tests", "stage_map_gspmd_check.py")],
+        capture_output=True, text=True, timeout=900, env=env,
+    )
+    out = proc.stdout + proc.stderr
+    assert proc.returncode == 0, out[-3000:]
+    rows = {r["path"]: r for r in (
+        json.loads(line[len("CHECK "):]) for line in out.splitlines()
+        if line.startswith("CHECK "))}
+    assert set(rows) == {"per_leaf", "flat", "flat_device_pack"}
+    every = {"forward_backward", "optimizer", "select", "quantize",
+             "all_gather", "decode", "apply"}
+    for path, row in rows.items():
+        assert row["clients"] == 4
+        want = every | ({"pack"} if path == "flat_device_pack" else set())
+        assert set(row["stages"]) == want, row
