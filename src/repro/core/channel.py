@@ -62,6 +62,7 @@ from repro.core.ledger import BandwidthLedger, RoundRecord
 from repro.obs import NULL_TELEMETRY
 from repro.obs.scopes import scope
 from repro.core.policy import CompressionPolicy, CompressorState, ResolvedPolicy
+from repro.core.select import two_sided_topk, uses_threshold
 from repro.core.wire import Wire, wire_for
 
 def shard_map(f, *, mesh, in_specs, out_specs):
@@ -177,6 +178,22 @@ def analytic_bits(resolved: ResolvedPolicy, leaves: Sequence,
     return ChannelBits(per_client=per_client, dense=dense)
 
 
+def threshold_share(resolved: ResolvedPolicy, leaves: Sequence) -> float:
+    """Share of the sparse leaves' elements whose two-sided top-k takes
+    the threshold path of :mod:`repro.core.select` (the rest keep
+    ``lax.top_k``); 0 when no leaf is sparse."""
+    sparse = routed = 0
+    for plan, leaf in zip(resolved.plans, leaves):
+        codec = plan.codec
+        if codec.skip or codec.selector.dense:
+            continue
+        n = int(np.prod(getattr(leaf, "shape", np.shape(leaf))) or 1)
+        sparse += n
+        if codec.selector.name == "topk_signed" and uses_threshold(n):
+            routed += n
+    return routed / sparse if sparse else 0.0
+
+
 # ============================================================ local backend
 
 
@@ -211,6 +228,9 @@ class LocalVmapChannel:
     def resolved(self, params: PyTree) -> ResolvedPolicy:
         if self._resolved is None:
             self._resolved = resolve_cached(self.compressor.policy, params)
+            self.telemetry.metrics.gauge(
+                "select/threshold_share",
+                threshold_share(self._resolved, self._resolved._leaves_of(params)))
         return self._resolved
 
     def init_state(self, params: PyTree, rng: jax.Array) -> CompressorState:
@@ -336,8 +356,7 @@ def _sbc_local(acc_flat: jax.Array, p: float, client_axes, n_clients: int,
     def one_layer(_, x_row):
         with scope("select"):
             x = x_row.astype(jnp.float32)
-            val_pos, idx_pos = jax.lax.top_k(x, k)
-            val_neg, idx_neg = jax.lax.top_k(-x, k)
+            (val_pos, idx_pos), (val_neg, idx_neg) = two_sided_topk(x, k)
             mu_pos, mu_neg = jnp.mean(val_pos), jnp.mean(val_neg)
             pos_wins = mu_pos > mu_neg
             idx = jnp.where(pos_wins, idx_pos, idx_neg).astype(jnp.int32)

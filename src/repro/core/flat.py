@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.golomb import golomb_bstar
+from repro.core.select import two_sided_topk
 from repro.core.stages import LeafCompressed, k_for
 from repro.obs.scopes import scope
 from repro.kernels.ops import seg_sbc_hist
@@ -273,8 +274,7 @@ class FlatParamSpace:
                 )
                 continue
             with scope("select"):
-                val_pos, idx_pos = jax.lax.top_k(acc, k)
-                val_neg, idx_neg = jax.lax.top_k(-acc, k)
+                (val_pos, idx_pos), (val_neg, idx_neg) = two_sided_topk(acc, k)
                 pos_wins = jnp.mean(val_pos) > jnp.mean(val_neg)
                 idx = jnp.where(pos_wins, idx_pos, idx_neg).astype(jnp.int32)
             # μ re-gathers the winning side's ORIGINAL values, exactly like
@@ -611,8 +611,8 @@ class ShardedFlatParamSpace:
             k = s.k
 
             def one_layer(_, x_row, k=k):
-                val_pos, idx_pos = jax.lax.top_k(x_row, k)
-                val_neg, idx_neg = jax.lax.top_k(-x_row, k)
+                (val_pos, idx_pos), (val_neg, idx_neg) = two_sided_topk(
+                    x_row, k)
                 mu_pos, mu_neg = jnp.mean(val_pos), jnp.mean(val_neg)
                 pos_wins = mu_pos > mu_neg
                 idx = jnp.where(pos_wins, idx_pos, idx_neg).astype(jnp.int32)

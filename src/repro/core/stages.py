@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.golomb import expected_position_bits
+from repro.core.select import two_sided_topk
 
 
 class LeafCompressed(NamedTuple):
@@ -156,11 +157,12 @@ def make_topk_signed_selector(**_) -> Selector:
     def fn(flat, p, rng):
         del rng
         k = k_for(flat.shape[0], p)
-        val_pos, idx_pos = jax.lax.top_k(flat, k)
-        val_neg, idx_neg = jax.lax.top_k(-flat, k)
+        (val_pos, idx_pos), (val_neg, idx_neg) = two_sided_topk(flat, k)
         pos_wins = jnp.mean(val_pos) > jnp.mean(val_neg)
         idx = jnp.where(pos_wins, idx_pos, idx_neg).astype(jnp.int32)
-        return Selection(idx=idx, vals=flat[idx])
+        # flat[idx] without the gather: −val_neg is flat's own bits
+        vals = jnp.where(pos_wins, val_pos, -val_neg)
+        return Selection(idx=idx, vals=vals)
 
     return Selector("topk_signed", fn, flat_fast=True)
 

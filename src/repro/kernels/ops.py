@@ -21,7 +21,8 @@ elsewhere).
 
 ``sbc_compress_hist`` is the one-segment pipeline and returns everything
 the trainer's exchange needs.  ``sbc_compress_exact`` is the faithful
-``lax.top_k`` path.
+top-k path (:func:`repro.core.select.two_sided_topk`, bit-identical to
+``lax.top_k``).
 """
 from __future__ import annotations
 
@@ -32,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.golomb import expected_position_bits
+from repro.core.select import two_sided_topk
 from repro.kernels.flat import (
     seg_binarize_apply,
     seg_hist2side,
@@ -235,13 +237,12 @@ def sbc_compress_hist(
 
 @functools.partial(jax.jit, static_argnames=("p",))
 def sbc_compress_exact(acc: jax.Array, *, p: float) -> SBCCompressed:
-    """Faithful Alg. 2 via lax.top_k (exactly k survivors)."""
+    """Faithful Alg. 2: the exact two-sided top-k (exactly k survivors)."""
     n = acc.shape[0]
     k = max(1, min(n, int(round(p * n))))
     x = acc.astype(jnp.float32)
 
-    val_pos, idx_pos = jax.lax.top_k(x, k)
-    val_neg, idx_neg = jax.lax.top_k(-x, k)
+    (val_pos, idx_pos), (val_neg, idx_neg) = two_sided_topk(x, k)
     mu_pos = jnp.mean(val_pos)
     mu_neg = jnp.mean(val_neg)
     pos_wins = mu_pos > mu_neg

@@ -55,6 +55,13 @@ METRIC_NAMES: Dict[str, Tuple[str, str]] = {
     "leaf/golomb_bits_pos": (
         "gauge", "Eq. 5 expected Golomb bits per position at rate p (tag: leaf)",
     ),
+    # ---- selection
+    "select/threshold_share": (
+        "gauge",
+        "share of sparse-leaf elements whose exact two-sided top-k takes "
+        "the threshold path instead of a full sort (local backend; once "
+        "per policy resolve)",
+    ),
     # ---- training trajectory
     "train/loss": ("gauge", "mean client loss this round"),
     "train/bits_per_client": ("gauge", "analytic upstream bits per client"),
