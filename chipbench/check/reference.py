@@ -1,8 +1,9 @@
 """Plain reference of distributed SGD with Sparse Binary Compression.
 
-Follows the paper's Alg. 1 and Alg. 2 with the configuration's Adam, one client after another, in plain ``jax.numpy``.  It
-imports nothing of the program and takes nothing that the program made:
-the weights and batches come from the benchmark's own seeded generators.
+Follows the paper's Alg. 1 and Alg. 2 with the configuration's Adam, one
+client after another, in plain ``jax.numpy``.  It imports nothing of the
+program and takes nothing that the program made: the weights and batches
+come from the benchmark's own seeded generators.
 
 One round, for every client c:
 
@@ -16,11 +17,18 @@ One round, for every client c:
      signed mean mu; ``none`` sends A_c whole;
   4. R_c <- A_c - S_c; the optimizer's momentum is zeroed where S_c != 0.
 
-Then W <- W + (1/C) sum_c S_c.  ``dtype`` sets the type of every array
-(the control runs the same code in bfloat16); ``fault`` plants one of the
-faults the calibration reads (see ``FAULTS``): half of every batch left
-out, the exchange left out (client 0 applies its own update), or the
-first leaf's update doubled in every round where the round produces it.
+Then W <- W + (1/C) sum_c S_c.  The reference works leaf by leaf so
+that it fits beside a model that fills the chip: the device holds W, the
+client's W_c, one step's gradient and the running mean (16 bytes a
+float32 parameter), and one leaf's Adam update in flight (its W_c, m and
+v in and out); every client's m, v and R_c, and the initial weights,
+live on the host, each leaf moved to the device for its own update only.
+
+``dtype`` sets the type of every array (the control runs the same code
+in bfloat16); ``fault`` plants one of the faults the calibration reads
+(see ``FAULTS``): half of every batch left out, the exchange left out
+(client 0 applies its own update), or the first leaf's update doubled in
+every round where the round produces it.
 """
 import jax
 import jax.numpy as jnp
@@ -64,8 +72,9 @@ class Reference:
         if self.opt["name"] != "adam":
             raise ValueError(f"the reference knows Adam, not {self.opt['name']!r}")
         self.clients, self.delay = traffic["clients"], traffic["delay"]
-        self._local = jax.jit(self._local_steps)
-        self._compress = jax.jit(self._compress_tree)
+        self._grad = jax.jit(jax.value_and_grad(self._loss))
+        self._adam = jax.jit(self._adam_leaf)
+        self._compress = jax.jit(self._compress_leaf, static_argnums=1)
 
     # ------------------------------------------------------------ one client
 
@@ -74,92 +83,100 @@ class Reference:
             batch = jax.tree.map(lambda x: x[: x.shape[0] // 2], batch)
         return self.mod.loss(self.cfg, params, batch)
 
-    def _local_steps(self, w, m, v, batches, t0):
-        """``delay`` optimizer steps from w; batches lead with the delay
-        axis; ``t0`` is the optimizer's step count before the first."""
+    def _adam_leaf(self, w, m, v, g, t):
+        """One Adam step of one leaf; ``t`` counts the optimizer's steps
+        with this one."""
         o, dt = self.opt, self.dtype
         f32 = jnp.float32
         # scalars stay float32 (0.999 is 1 in bfloat16); every array is
         # rounded back to ``dt`` after each update
         lr = jnp.asarray(o["lr"], f32)
         cast = lambda x: x.astype(dt)  # noqa: E731
+        b1, b2 = jnp.asarray(o["b1"], f32), jnp.asarray(o["b2"], f32)
+        m = cast(b1 * m + (1 - b1) * g)
+        v = cast(b2 * v + (1 - b2) * g * g)
+        c1, c2 = 1 - b1 ** t, 1 - b2 ** t
+        w = cast(w - lr * (m / c1) / (jnp.sqrt(v / c2) + o["eps"]))
+        return w, m, v
 
-        def one(carry, batch):
-            w, m, v, t = carry
-            loss, g = jax.value_and_grad(self._loss)(w, batch)
-            b1, b2 = jnp.asarray(o["b1"], f32), jnp.asarray(o["b2"], f32)
-            t = t + 1.0
-            m = jax.tree.map(lambda m, g: cast(b1 * m + (1 - b1) * g), m, g)
-            v = jax.tree.map(lambda v, g: cast(b2 * v + (1 - b2) * g * g), v, g)
-            c1, c2 = 1 - b1 ** t, 1 - b2 ** t
-            w = jax.tree.map(
-                lambda w, m, v: cast(w - lr * (m / c1) / (jnp.sqrt(v / c2) + o["eps"])),
-                w, m, v)
-            return (w, m, v, t), loss
-
-        (w, m, v, _), losses = jax.lax.scan(one, (w, m, v, t0), batches)
-        return w, m, v, jnp.mean(losses)
-
-    def _compress_tree(self, acc):
+    def _compress_leaf(self, x, rows):
         if self.traffic["compressor"] == "none":
-            return acc
-        p = self.traffic["sparsity"]
-        paths = leaf_paths(acc)
-        leaves, treedef = jax.tree.flatten(acc)
-        out = []
-        for path, x in zip(paths, leaves):
-            rows = self.mod.tensor_rows(self.cfg, path)
-            out.append(_sbc_rows(x.reshape(rows, -1), p).reshape(x.shape))
-        return jax.tree.unflatten(treedef, out)
+            return x
+        return _sbc_rows(x.reshape(rows, -1), self.traffic["sparsity"]).reshape(x.shape)
+
+    def _client(self, w, treedef, rows, host, batch, step0):
+        """One client's round from the weights ``w`` (device leaves).
+        ``host`` = (m, v, r), the client's Adam moments and residual as
+        host leaves; each leaf visits the device only for its own update.
+        Returns the client's S_c (device leaves), its mean loss over the
+        local steps and its new (m, v, r) on the host."""
+        dev = next(iter(w[0].devices()))
+        m, v, r = (list(x) for x in host)
+        wc, losses = list(w), []
+        for j in range(self.delay):
+            step = jax.tree.map(lambda x: x[j], batch)
+            loss, g = self._grad(jax.tree.unflatten(treedef, wc), step)
+            losses.append(loss)
+            t = jnp.asarray(step0 + j + 1, jnp.float32)
+            for i, gi in enumerate(jax.tree.leaves(g)):
+                wc[i], mi, vi = self._adam(wc[i], jax.device_put(m[i], dev),
+                                           jax.device_put(v[i], dev), gi, t)
+                m[i], v[i] = jax.device_get((mi, vi))
+                del mi, vi  # else held on the device through the next update
+            del g
+        s = []
+        for i, n_rows in enumerate(rows):
+            acc = jax.device_put(r[i], dev) + (wc[i] - w[i])
+            wc[i] = None
+            s.append(self._compress(acc, n_rows))
+            r[i] = jax.device_get(acc - s[i])
+            del acc
+            m[i] = jax.device_get(jnp.where(s[i] != 0, 0, jax.device_put(m[i], dev)))
+        return s, float(jnp.mean(jnp.stack(losses))), (m, v, r)
 
     # ----------------------------------------------------------------- rounds
 
-    def run(self, params0, batches, rounds=3):
-        """``rounds`` rounds from ``params0``; ``batches[r]`` leads with
-        (clients, delay).  Returns the evidence the comparison reads:
-        per-round losses, per-leaf gradient evidence after round 1 and
-        per-leaf norms of the parameters' change after ``rounds``."""
-        dt = self.dtype
-        cast = lambda t: jax.tree.map(lambda x: x.astype(dt), t)
-        zeros = lambda t: jax.tree.map(jnp.zeros_like, t)
-        w = cast(params0)
-        ms = [zeros(w) for _ in range(self.clients)]
-        vs = [zeros(w) for _ in range(self.clients)]
-        rs = [zeros(w) for _ in range(self.clients)]
+    def run(self, params0, batches, rounds=3, device=None):
+        """``rounds`` rounds from ``params0`` (host arrays) on ``device``;
+        ``batches[r]`` leads with (clients, delay).  Returns the evidence
+        the comparison reads: per-round losses, per-leaf gradient evidence
+        after round 1 and per-leaf norms of the parameters' change after
+        ``rounds``."""
+        dt, device = self.dtype, device or jax.devices()[0]
+        paths = leaf_paths(params0)
+        w, treedef = jax.tree.flatten(
+            jax.tree.map(lambda x: jax.device_put(x, device).astype(dt), params0))
+        rows = [self.mod.tensor_rows(self.cfg, path) for path in paths]
+        zeros = lambda: [np.zeros(x.shape, x.dtype) for x in w]  # noqa: E731
+        state = [(zeros(), zeros(), zeros()) for _ in range(self.clients)]
         losses, grad_evidence = [], None
         for r in range(rounds):
-            step0 = r * self.delay
-            round_losses, mean = [], None
+            round_losses, mean = [], [None] * len(w)
             for c in range(self.clients):
                 batch = jax.tree.map(lambda x: x[c], batches[r])
                 if "images" in batch:
                     batch["images"] = batch["images"].astype(dt)
-                wc, ms[c], vs[c], loss = self._local(
-                    w, ms[c], vs[c], batch, jnp.asarray(step0, jnp.float32))
-                acc = jax.tree.map(lambda a, b, res: res + (a - b), wc, w, rs[c])
-                del wc
-                s = self._compress(acc)
-                rs[c] = jax.tree.map(lambda a, b: a - b, acc, s)
-                del acc
-                ms[c] = jax.tree.map(lambda m, x: jnp.where(x != 0, 0, m), ms[c], s)
-                round_losses.append(float(loss))
+                s, loss, state[c] = self._client(w, treedef, rows, state[c], batch,
+                                                 r * self.delay)
+                round_losses.append(loss)
                 if self.fault == "no_exchange":  # client 0 applies its own
                     mean = s if c == 0 else mean
-                else:  # clients added in order, as sum_c S_c / C
-                    part = jax.tree.map(lambda x: x / self.clients, s)
-                    mean = part if mean is None else jax.tree.map(jnp.add, mean, part)
-                del s
+                    continue
+                for i in range(len(s)):  # clients added in order, as sum_c S_c / C
+                    part, s[i] = s[i] / self.clients, None
+                    mean[i] = part if c == 0 else mean[i] + part
             if self.fault == "scaled_update":
-                leaves, treedef = jax.tree.flatten(mean)
-                mean = jax.tree.unflatten(treedef, [leaves[0] * 2] + leaves[1:])
-            w = jax.tree.map(lambda a, b: a + b, w, mean)
+                mean[0] = mean[0] * 2
+            w = [a + b for a, b in zip(w, mean)]
             del mean
             losses.append(float(np.mean(round_losses)))
             if r == 0:
-                grad_evidence = adam_evidence(vs)
-        change, support = np.asarray(change_evidence(w, params0))
+                grad_evidence = adam_evidence(
+                    [jax.tree.unflatten(treedef, v) for _, v, _ in state])
+        change, support = np.asarray(change_evidence(jax.tree.unflatten(treedef, w),
+                                                     params0))
         return {"losses": losses, "grad": grad_evidence, "change": change,
-                "support": support, "paths": leaf_paths(params0)}
+                "support": support, "paths": paths}
 
 
 @jax.jit

@@ -3,9 +3,12 @@
 ``workloads/<cell>.json`` names a configuration and a traffic mix and
 holds the cell's limits; ``traffic_mixes/<mix>.json`` gives the traffic;
 ``configs/<config>.json`` holds the sizes and ``configs/<config>.py`` the
-plain reference beside them.  Nothing here names a cell, a configuration
-or a per-layer metric: ``BENCHMARK.json`` lists the metrics, and each
-per-layer metric is read by ``layer_metrics/<metric>.py``.
+plain reference beside them; ``inputs/<kind>.py`` makes the batches of
+the kind the configuration's ``inputs`` names.  Nothing here names a
+cell, a configuration, an input kind or a per-layer metric:
+``BENCHMARK.json`` lists the metrics, and each per-layer metric is read
+by ``layer_metrics/<metric>.py``.  The mix's ``seq_len``, where it has
+one, is the program's sequence length.
 
 A run:
 
@@ -66,6 +69,7 @@ class Cell:
     traffic: dict
     cfg: dict
     mod: object  # the configuration's plain reference
+    inputs: object  # its input kind, ``inputs/<kind>.py``
 
     @property
     def chips(self):
@@ -77,7 +81,20 @@ class Cell:
         return t["clients"] * t["delay"] * t["batch"]
 
 
-def validate(workload, t):
+def input_kind(name, bench=BENCH):
+    """The module of input kind ``name``: ``inputs/<name>.py``, whose
+    ``make(cfg, traffic, key, lead)`` returns a batch with leading axes
+    ``lead`` and whose ``TRAFFIC_KEYS`` names what the mix must state."""
+    path = Path(bench) / "inputs" / f"{name}.py"
+    if not path.is_file():
+        raise ValueError(f"no input kind {name!r}: {path} does not exist")
+    return load_module(path, "chipbench_inputs_" + name.replace(".", "_").replace("-", "_"))
+
+
+def validate(workload, t, cfg, kind):
+    """Refuse a cell that the harness cannot run as its files state: another
+    backend than the local one, more chips, a compressor the reference does
+    not know, or a mix that lacks what the input kind ``kind`` needs."""
     if t["backend"] != "local":
         why = ("runs one local step per round whatever delay says"
                if t["delay"] > 1 else "restarts Adam's bias correction every round")
@@ -89,17 +106,23 @@ def validate(workload, t):
         raise ValueError(f"{workload['name']}: the local backend runs on one chip")
     if t["compressor"] not in ("sbc", "none"):
         raise ValueError(f"{workload['name']}: the reference knows sbc and none")
+    missing = [k for k in kind.TRAFFIC_KEYS if k not in t]
+    if missing:
+        raise ValueError(f"{workload['name']}: the {cfg['inputs']} inputs of "
+                         f"{cfg['name']} need {missing} in the traffic mix")
 
 
 def load_cell(name, bench=BENCH):
     bench = Path(bench)
     workload = json.loads((bench / "workloads" / f"{name}.json").read_text())
     traffic = json.loads((bench / "traffic_mixes" / f"{workload['traffic']}.json").read_text())
-    validate(workload, traffic)
     cfg = json.loads((bench / "configs" / f"{workload['config']}.json").read_text())
+    inputs = input_kind(cfg["inputs"], bench)
+    validate(workload, traffic, cfg, inputs)
     mod = load_module(bench / "configs" / cfg["reference"],
                       "chipbench_ref_" + cfg["name"].replace("-", "_").replace(".", "_"))
-    return Cell(name=name, workload=workload, traffic=traffic, cfg=cfg, mod=mod)
+    return Cell(name=name, workload=workload, traffic=traffic, cfg=cfg, mod=mod,
+                inputs=inputs)
 
 
 def seed_key(seed):
@@ -158,7 +181,7 @@ class Program:
             preset=cfg["preset"], backend="local", compressor=t["compressor"],
             sparsity=t.get("sparsity", 0.001), delay=t["delay"], clients=t["clients"],
             batch=t["batch"], measure_wire=t.get("measure_wire", False), telemetry=False,
-            seed=seed & 0x7FFFFFFF)
+            seed=seed & 0x7FFFFFFF, **({"seq_len": t["seq_len"]} if "seq_len" in t else {}))
         self.run = build_run(self.spec)
         self.abstract = jax.eval_shape(self.run.init, jax.random.PRNGKey(0))
         self._check_sizes()
@@ -310,7 +333,7 @@ def setup_rounds(prog, seed):
 
     cell, run = prog.cell, prog.run
     k_weights, k_data = keys_for(seed)
-    prog.feed(traffic_mod.make_pool(cell.cfg, cell.traffic, k_data),
+    prog.feed(traffic_mod.make_pool(cell.inputs.make, cell.cfg, cell.traffic, k_data),
               traffic_mod.POOL_ROUNDS)
     state = prog.make_state(k_weights)
     losses, grad, counters, capture_s = [], None, {}, 0.0
@@ -340,15 +363,14 @@ def reference_evidence(cell, seed, device, dtype=None, fault=None):
     from chipbench.check.reference import Reference
 
     k_weights, k_data = keys_for(seed)
-    pool = traffic_mod.make_pool(cell.cfg, cell.traffic, k_data)
+    pool = traffic_mod.make_pool(cell.inputs.make, cell.cfg, cell.traffic, k_data)
     batches = [jax.tree.map(lambda x: np.asarray(x[i]), pool) for i in range(SETUP_ROUNDS)]
     del pool
-    params0 = jax.device_put(
-        jax.jit(lambda k: cell.mod.init_params(cell.cfg, k))(k_weights), device)
+    params0 = jax.device_get(jax.jit(lambda k: cell.mod.init_params(cell.cfg, k))(k_weights))
     ref = Reference(cell.mod, cell.cfg, cell.traffic,
                     dtype=dtype or jnp.float32, fault=fault)
     with jax.default_matmul_precision("highest"):
-        return ref.run(params0, batches, SETUP_ROUNDS)
+        return ref.run(params0, batches, SETUP_ROUNDS, device)
 
 
 def evidence_json(prog, ref):
@@ -388,10 +410,12 @@ def run_cell(name, seed, seconds, trace, *, bench=BENCH, bench_json=None,
     use_compile_cache()
     t = cell.traffic
 
+    t_chip = time.perf_counter()
     prog = Program(cell, seed)
     if patch is not None:
         patch(prog)
     run = prog.run
+    t_built = time.perf_counter()
     su = setup_rounds(prog, seed)
     state = su.state
     su.state = None
@@ -401,7 +425,9 @@ def run_cell(name, seed, seconds, trace, *, bench=BENCH, bench_json=None,
     gc.freeze()
     setup_s = time.perf_counter() - t_start - su.capture_s
     log(f"[{name}] set-up {setup_s:.1f} s (check capture {su.capture_s:.1f} s "
-        f"apart), losses {su.evidence['losses']}, counters {su.counters}")
+        f"apart): {t_chip - t_start:.1f} s to the chip, {t_built - t_chip:.1f} s to "
+        f"build the program, the rest for state, inputs and rounds 0-2; losses "
+        f"{su.evidence['losses']}, counters {su.counters}")
 
     # ---- the window
     times, r = [], SETUP_ROUNDS
@@ -477,7 +503,11 @@ def run_cell(name, seed, seconds, trace, *, bench=BENCH, bench_json=None,
                      if devs else []}
 
     # ---- correctness: the reference replays rounds 0-2
+    before = _device_info(devices)["memory_peak_bytes"]
     ref = reference_evidence(cell, seed, devices[0])
+    log(f"[{name}] peak memory {device['memory_peak_bytes']} B after the window, "
+        f"{before} B before the reference, "
+        f"{_device_info(devices)['memory_peak_bytes']} B after it")
     values = check_numbers(cell, su, ref, log)
     log(f"[{name}] evidence {json.dumps(evidence_json(su.evidence, ref))}")
     if not trace:

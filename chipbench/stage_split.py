@@ -46,10 +46,11 @@ def _stage_map(ctx):
     mod = harness.load_module(harness.BENCH / "configs" / ctx.cfg["reference"],
                               "chipbench_stage_ref")
     cell = harness.Cell(name=f"{ctx.cfg['name']}.{ctx.traffic['name']}", workload={},
-                        traffic=ctx.traffic, cfg=ctx.cfg, mod=mod)
+                        traffic=ctx.traffic, cfg=ctx.cfg, mod=mod,
+                        inputs=harness.input_kind(ctx.cfg["inputs"]))
     prog = harness.Program(cell, 0)
     k_weights, k_data = harness.keys_for(0)
-    prog.feed(traffic_mod.make_pool(cell.cfg, cell.traffic, k_data),
+    prog.feed(traffic_mod.make_pool(cell.inputs.make, cell.cfg, cell.traffic, k_data),
               traffic_mod.POOL_ROUNDS)
     state = prog.make_state(k_weights)
     return prog.run.stage_map(state, harness.SETUP_ROUNDS)

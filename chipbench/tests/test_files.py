@@ -71,13 +71,15 @@ def test_every_config_is_used_and_every_metric_has_a_reader():
 def test_gspmd_cell_with_delay_is_refused():
     w = {"name": "x", "chips": 1}
     t = {"backend": "gspmd", "compressor": "sbc", "delay": 10, "clients": 1}
+    cfg = {"name": "lenet5", "inputs": "images"}
+    kind = harness.input_kind("images")
     with pytest.raises(ValueError, match="delay"):
-        harness.validate(w, t)
+        harness.validate(w, t, cfg, kind)
     with pytest.raises(ValueError, match="bias correction"):
-        harness.validate(w, dict(t, delay=1))
-    harness.validate(w, dict(t, backend="local"))
+        harness.validate(w, dict(t, delay=1), cfg, kind)
+    harness.validate(w, dict(t, backend="local"), cfg, kind)
     with pytest.raises(ValueError, match="one chip"):
-        harness.validate(dict(w, chips=4), dict(t, backend="local"))
+        harness.validate(dict(w, chips=4), dict(t, backend="local"), cfg, kind)
 
 
 def test_config_file_states_the_program_and_its_source():

@@ -7,13 +7,12 @@ cell: a pool of ``POOL_ROUNDS`` rounds of batches, each with a leading
 replays) all have distinct rows, and the timed window drives the program
 with no input generation in it.
 
-Input kinds, named by the configuration's ``inputs`` key:
-
-  images  Gaussian class blobs: fixed class means (0.5 N(0,1)) plus
-          ``noise`` N(0,1), labels uniform over the classes.
+What a batch holds is the input kind that the configuration's ``inputs``
+key names (``inputs/<kind>.py``, loaded by ``harness.input_kind``): its
+``make(cfg, traffic, key, lead)`` returns the batch with leading axes
+``lead``.  A new kind is a new file.
 """
 import jax
-import jax.numpy as jnp
 
 POOL_ROUNDS = 8
 
@@ -23,22 +22,8 @@ def pool_shape(cfg, traffic):
     return (traffic["clients"], traffic["delay"], traffic["batch"])
 
 
-def _images(cfg, traffic, key, lead):
-    size, ch, ncls = cfg["img_size"], cfg["img_channels"], cfg["n_classes"]
-    k_means, k_lab, k_noise = jax.random.split(key, 3)
-    means = 0.5 * jax.random.normal(k_means, (ncls, size, size, ch))
-    labels = jax.random.randint(k_lab, lead, 0, ncls)
-    noise = traffic.get("noise", 0.35) * jax.random.normal(
-        k_noise, lead + (size, size, ch))
-    return {"images": means[labels] + noise, "labels": labels.astype(jnp.int32)}
-
-
-KINDS = {"images": _images}
-
-
-def make_pool(cfg, traffic, key):
-    """``POOL_ROUNDS`` rounds of batches, leading axes
-    ``(POOL_ROUNDS, clients, delay, batch)``, in one jitted call."""
+def make_pool(make, cfg, traffic, key):
+    """``POOL_ROUNDS`` rounds of the input kind ``make``'s batches, leading
+    axes ``(POOL_ROUNDS, clients, delay, batch)``, in one jitted call."""
     lead = (POOL_ROUNDS,) + pool_shape(cfg, traffic)
-    make = KINDS[cfg["inputs"]]
     return jax.jit(lambda k: make(cfg, traffic, k, lead))(key)
